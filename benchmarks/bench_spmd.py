@@ -11,9 +11,10 @@ shard-aware tier, report — per mesh shape (1×1, 2×1, 2×2) —
   under ``shard_map`` vs the single-device unfused oracle, and the
   allclose check against that oracle (``max_rel_err``).
 
-Mesh sizes beyond the host's device count are simulated per-row in a
-subprocess with ``--xla_force_host_platform_device_count`` (the parent
-process keeps its 1-device backend — same pattern as tests/distributed).
+Every mesh is simulated per-row in a subprocess pinned to the CPU
+(``JAX_PLATFORMS=cpu``) with ``--xla_force_host_platform_device_count``:
+the rows say ``platform: cpu``, and a worker never reaches for an
+accelerator the parent process may already hold.
 Rows land in ``BENCH_spmd.json`` via ``benchmarks/run.py`` so successive
 PRs leave a trajectory; ``scripts/check_bench.py`` gates launch-count
 regressions in CI.
@@ -30,8 +31,6 @@ import textwrap
 
 _WORKER = textwrap.dedent(
     """
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(ndev)d"
     import sys
     sys.path.insert(0, %(src)r)
     import json, time
@@ -43,6 +42,7 @@ _WORKER = textwrap.dedent(
     from repro.core.infer import abstract_of_value
     from repro.core.jax_backend import compile_graph_spmd
     from repro.core.lowering import lower_graph
+    from repro.launch.mesh import make_local_mesh
 
     MESH = %(mesh)r
     REPS = %(reps)d
@@ -78,7 +78,7 @@ _WORKER = textwrap.dedent(
         ts.sort()
         return ts[len(ts) // 2]
 
-    run = compile_graph_spmd(g, jax.make_mesh(MESH, ("data", "model")), in_specs, fuse=True)
+    run = compile_graph_spmd(g, make_local_mesh(*MESH), in_specs, fuse=True)
     got = run(*args)
     rel = max(
         float(np.max(np.abs(np.asarray(a) - np.asarray(b)))
@@ -90,6 +90,7 @@ _WORKER = textwrap.dedent(
     row = {
         "workload": "mlp_adjoint_dp",
         "mesh": "x".join(map(str, MESH)),
+        "platform": jax.devices()[0].platform,
         "devices": %(ndev)d,
         "launches_unfused": plan.launches_before,
         "launches_fused": plan.launches_after,
@@ -113,13 +114,22 @@ def run(reps: int = 30) -> list[dict]:
     src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
     rows = []
     for mesh, ndev in _MESHES:
+        env = dict(
+            os.environ,
+            JAX_PLATFORMS="cpu",
+            XLA_FLAGS=f"--xla_force_host_platform_device_count={ndev}",
+        )
         script = _WORKER % {"ndev": ndev, "src": src, "mesh": mesh, "reps": reps}
         with tempfile.NamedTemporaryFile("w", suffix="_bench_spmd.py", delete=False) as f:
             f.write(script)
             path = f.name
         try:
             res = subprocess.run(
-                [sys.executable, path], capture_output=True, text=True, timeout=600
+                [sys.executable, path],
+                capture_output=True,
+                text=True,
+                timeout=600,
+                env=env,
             )
         finally:
             os.unlink(path)

@@ -85,6 +85,10 @@ def main(argv=None) -> int:
     )
     args = ap.parse_args(argv)
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from . import (
         bench_ad_overhead,
         bench_compile_time,

@@ -682,29 +682,25 @@ class MyiaFunction:
         if lowered is not None:
             # Tiered compilation (only possible because the program is a
             # straight-line lowered function, not an interpreter trace):
-            # the first call compiles at a low XLA optimization level —
-            # a fraction of the full-opt compile time on CPU — and answers
-            # from that; the second call onwards uses the fully optimized
-            # ``jax.jit`` executable.  If the backend rejects the tier-0
-            # options, the first call simply takes the normal jit path.
+            # the first concrete call compiles at a low XLA optimization
+            # level — a fraction of the full-opt compile time on CPU — and
+            # answers from that; the second call onwards uses the fully
+            # optimized ``jax.jit`` executable.  Both the CPU and the TPU
+            # compilers accept the tier-0 options, so a failure here is a
+            # real compile error and propagates.
             state = {"calls": 0}
 
             def runner(*args):
                 arrs = [args[i] for i in dyn_idx]
                 state["calls"] += 1
-                if state["calls"] == 1:
-                    fast = None
-                    try:
-                        with obs_trace.span("xla.tier0_compile"):
-                            fast = jitted.lower(*arrs).compile(
-                                compiler_options=_TIER0_COMPILER_OPTIONS
-                            )
-                    except Exception:
-                        pass  # unknown option/backend: use the full jit
-                    if fast is not None:
-                        # outside the try: a genuine runtime error must
-                        # surface, not silently re-run under the full jit
-                        return fast(*arrs)
+                if state["calls"] == 1 and not any(
+                    isinstance(a, jax.core.Tracer) for a in arrs
+                ):
+                    with obs_trace.span("xla.tier0_compile"):
+                        fast = jitted.lower(*arrs).compile(
+                            compiler_options=_TIER0_COMPILER_OPTIONS
+                        )
+                    return fast(*arrs)
                 return jitted(*arrs)
         else:
             def runner(*args):

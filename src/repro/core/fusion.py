@@ -113,6 +113,8 @@ class DeclineReason:
     CLAIMED = "claimed-by-neighbor"
     #: the partitioner clustered it but codegen could not express it
     CODEGEN = "codegen-declined"
+    #: codegen found no kernel block that fits the VMEM budget
+    VMEM = "exceeds-vmem-budget"
 
     __slots__ = ("kind", "detail")
 

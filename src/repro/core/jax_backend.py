@@ -177,8 +177,6 @@ def compile_graph_spmd(
     recursion / higher-order calls, non-array parameters) — callers fall
     back to the single-device tier.
     """
-    from repro.parallel import shard_map
-
     mesh_axes = dict(mesh.shape)
     sharded = shard_graph(graph, in_specs, mesh_axes)
     fn = try_lower(sharded.graph, fuse=fuse)
@@ -186,12 +184,12 @@ def compile_graph_spmd(
         raise SpmdError(f"per-shard program of {graph.name} failed to lower")
 
     def wrap() -> Callable:
-        return shard_map(
+        return jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=sharded.in_partition,
             out_specs=sharded.out_partition,
-            check_rep=False,
+            check_vma=False,
         )
 
     if jit and fuse:
@@ -388,6 +386,13 @@ def abstract_value_signature(abstracts: Sequence[Any]) -> str:
     return ";".join(part(a) for a in abstracts)
 
 
+def _device_identity() -> tuple[str, str]:
+    """``(platform, device_kind)`` of the default device: an executable
+    compiled for one TPU generation must never be loaded on another."""
+    d = jax.devices()[0]
+    return d.platform, d.device_kind
+
+
 def _avals(example_args: Sequence[Any]) -> tuple:
     return tuple(
         a if isinstance(a, jax.ShapeDtypeStruct) else jax.ShapeDtypeStruct(a.shape, a.dtype)
@@ -402,7 +407,8 @@ class ProgramCache:
     **Executable tier** (``key``/``load_or_compile``, ``<key>.pkl``)::
 
         structural graph hash × abstract signature × fuse/kernel-mode ×
-        mesh descriptor × (jax version, serialize format, backend platform)
+        mesh descriptor × (jax version, serialize format, backend platform,
+        device kind)
 
     Each entry stores the serialized optimized graph (``repro.core.
     serialize``) and, best-effort, the serialized XLA executable
@@ -462,7 +468,7 @@ class ProgramCache:
             "mesh": meshdesc,
             "jax": jax.__version__,
             "format": FORMAT_VERSION,
-            "platform": jax.devices()[0].platform,
+            "device": _device_identity(),
         }
         return hashlib.sha256(
             json.dumps(payload, sort_keys=True, separators=(",", ":")).encode()

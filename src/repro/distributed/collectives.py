@@ -25,22 +25,6 @@ import jax.numpy as jnp
 __all__ = ["int8_allreduce", "ring_reduce_scatter_matmul", "compressed_psum_grads"]
 
 
-def _axis_size(axis_name: str) -> int:
-    """``jax.lax.axis_size`` appeared in newer jax; under shard_map,
-    ``psum(1, axis)`` constant-folds to the same concrete int everywhere."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def _pvary(x: jax.Array, axis_names: tuple[str, ...]) -> jax.Array:
-    """``jax.lax.pvary`` (varying-type annotation for the newer shard_map
-    type system) is a semantic no-op where it does not exist."""
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis_names)
-    return x
-
-
 def _quantize(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     scale = jnp.max(jnp.abs(x)) / 127.0 + 1e-12
     q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
@@ -58,7 +42,7 @@ def int8_allreduce(
     Phase 2 (all-gather): re-quantize the reduced chunk, ``all_gather``
     int8 + scales, dequantize.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     orig_shape = x.shape
     xf = x.reshape(-1).astype(jnp.float32)
     if err is not None:
@@ -99,7 +83,7 @@ def ring_reduce_scatter_matmul(
     for another block is in flight (``ppermute``) — the transfer of step
     s hides behind the matmul of step s+1.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     m = x_shard.shape[0]
     assert m % n == 0, (m, n)
@@ -119,14 +103,16 @@ def ring_reduce_scatter_matmul(
         acc = acc + part
         return jax.lax.ppermute(acc, axis_name, perm)
 
-    acc0 = _pvary(jnp.zeros((mb, w_shard.shape[1]), jnp.float32), (axis_name,))
+    acc0 = jax.lax.pcast(
+        jnp.zeros((mb, w_shard.shape[1]), jnp.float32), (axis_name,), to="varying"
+    )
     acc = jax.lax.fori_loop(0, n, body, acc0)
     return acc.astype(jnp.promote_types(x_shard.dtype, w_shard.dtype))
 
 
 def compressed_psum_grads(grads, axis_name: str, errs=None):
     """Tree-wide int8 error-feedback all-reduce (mean) for gradients."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if errs is None:
         errs = jax.tree.map(lambda g: jnp.zeros(g.shape, jnp.float32), grads)
     out = jax.tree.map(

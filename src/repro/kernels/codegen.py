@@ -15,25 +15,35 @@ with three interchangeable execution paths, selected by the *same*
   remain bit-identical).
 * ``"pallas"``               — the compiled Pallas TPU kernel.
 
-Kernel shape strategy:
+Kernel shape strategy (blocks are legal for the TPU's Mosaic compiler
+and sized to a VMEM budget, :data:`_VMEM_BUDGET`):
 
 * **map clusters** (elementwise root): the body shape ``S`` is collapsed
-  to 2-D ``(R, C) = (prod(S[:-1]), S[-1])`` and the grid blocks rows —
-  ``grid=(R/br,)`` with ``BlockSpec((br, C))`` per operand, ``br`` the
-  largest power-of-two row divisor that keeps a block within the VMEM
-  budget.  Every operand is materialized *at* ``S`` by the wrapper
-  (broadcast members run there; smaller external inputs are
-  ``broadcast_to``-ed), so the kernel body is pure per-block elementwise
-  code.
-* **reduce clusters** (reduction root): one whole-array block (no grid) —
-  the kernel computes the elementwise body and applies the reduction
+  to 2-D ``(R, C) = (prod(S[:-1]), S[-1])`` and the grid tiles it in
+  ``(br, bc)`` blocks, ``grid=(cdiv(R, br), cdiv(C, bc))``.  A block
+  dimension is either the whole array dimension or a multiple of the
+  tiling — ``br`` of the narrowest dtype's sublane count (8 rows of a
+  32-bit type, 16 of a 16-bit one, 32 of an 8-bit one), ``bc`` of the
+  128-lane width — so edge blocks may be partial, which elementwise
+  bodies tolerate.  Whole rows are kept when 8 of them fit the budget;
+  wider rows are blocked in columns too.  Every operand is materialized
+  *at* ``S`` by the wrapper (broadcast members run there; smaller
+  external inputs are ``broadcast_to``-ed), so the kernel body is pure
+  per-block elementwise code.
+* **reduce clusters** (reduction root): one whole-array block (no grid)
+  — the kernel computes the elementwise body and applies the reduction
   primitive with its static axes, so the floating-point reduction order
   is identical to the unfused lowering's.  Rank-0/1 results are staged
-  through a 2-D output block and reshaped by the wrapper.
+  through a 2-D output block and reshaped by the wrapper.  A reduce
+  cluster whose whole-array block does not fit the VMEM budget is
+  declined.
 
 ``emit_cluster`` *declines* (returns None) clusters it cannot express —
-non-array external inputs, rank-0 bodies — and the lowering falls back to
-the per-node jnp path for exactly that cluster, never the whole graph.
+non-array external inputs, rank-0 bodies, blocks over the VMEM budget —
+and the lowering falls back to the per-node jnp path for exactly that
+cluster, never the whole graph.  Non-array constants (a ``cast``'s
+dtype, python scalars) stay closure-bound in the kernel body; only array
+values become kernel operands.
 
 Generated source (kernel + wrapper + oracle) is kept on the result as
 ``FusedKernel.source`` — tests exec it and ``docs/fusion.md`` shows one.
@@ -58,8 +68,11 @@ from .ops import get_kernel_mode
 
 __all__ = ["FusedKernel", "emit_cluster", "emit_cluster_explained"]
 
-#: soft cap on elements per VMEM block for generated map kernels
-_BLOCK_ELEMS = 128 * 1024
+#: VMEM bytes a generated kernel plans for: half of the TPU v5e's 16 MiB
+#: scoped-VMEM default, leaving the rest to Mosaic's own scratch
+_VMEM_BUDGET = 8 * 1024 * 1024
+#: lane width: the minor block dimension is a multiple of it (or whole)
+_LANE = 128
 
 _counter = [0]
 
@@ -151,20 +164,30 @@ def _literal(value: Any) -> str | None:
     return None
 
 
-def _const_shape(value: Any) -> tuple | None:
-    try:
-        return tuple(int(d) for d in np.shape(value))
-    except Exception:
+def _sublanes(itemsize: int) -> int:
+    """Row tiling of a dtype: 8 rows of 32-bit values, 16 of 16-bit, 32
+    of 8-bit (a bool is stored as one byte)."""
+    return max(8, 32 // itemsize)
+
+
+def _map_block(R: int, C: int, sub: int, elem_bytes: int) -> tuple[int, int] | None:
+    """``(br, bc)`` for an ``(R, C)`` map body whose every element costs
+    ``elem_bytes`` of VMEM, or None when even one minimal tile does not
+    fit.  Each block dimension is the whole dimension or a multiple of
+    its tiling (``sub`` rows, ``_LANE`` columns)."""
+    cap = _VMEM_BUDGET // elem_bytes  # elements per block
+    if R * C <= cap:
+        return R, C
+    if min(R, sub) * C <= cap:
+        bc = C  # whole rows fit: block rows only
+    else:
+        bc = (cap // sub) // _LANE * _LANE
+        if bc == 0:
+            return None
+    br = (cap // bc) // sub * sub
+    if br == 0:
         return None
-
-
-def _block_rows(R: int, C: int) -> int:
-    """Largest power-of-two divisor of R whose block stays under the VMEM
-    budget (falls back to R itself when R is odd — correctness first)."""
-    br = R
-    while br > 1 and br % 2 == 0 and br * max(C, 1) > _BLOCK_ELEMS:
-        br //= 2
-    return br
+    return min(br, R), bc
 
 
 def _abstract_nbytes(ab: Any) -> int:
@@ -266,8 +289,8 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
     def ext_shape(node: Node) -> tuple | None:
         if isinstance(node.abstract, AArray):
             return node.abstract.shape
-        if isinstance(node, Constant):
-            return _const_shape(node.value)
+        if isinstance(node, Constant):  # an array constant (see operand_for)
+            return tuple(int(d) for d in np.shape(node.value))
         return None
 
     pre_name: dict[int, str] = {}
@@ -304,7 +327,12 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
             expr, shape = pre_name[a._id], body_shape
         else:
             r = ext_ref(a)
-            if r is None or r[0] not in "a_":  # literal: embedded, not an operand
+            if r is None or (
+                isinstance(a, Constant)
+                and not isinstance(a.value, (np.ndarray, jax.Array))
+            ):
+                # literal or non-array static (a cast's dtype, a numpy
+                # scalar): embedded in the kernel body, not an operand
                 return r
             shape = ext_shape(a)
             if shape is None:
@@ -343,11 +371,36 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
         )
     root_v = vname[cluster.root._id]
 
-    # -- shapes ------------------------------------------------------------
+    # -- shapes & VMEM plan -------------------------------------------------
     C = body_shape[-1]
     R = int(np.prod(body_shape[:-1])) if len(body_shape) > 1 else 1
-    br = _block_rows(R, C)
     out2 = (1, max(int(np.prod(out_shape)), 1)) if len(out_shape) < 2 else None
+    sizes = [
+        np.dtype(n.abstract.dtype).itemsize
+        for n in cluster.order
+        if isinstance(n.abstract, AArray)
+    ]
+    widest, narrowest = max(sizes), min(sizes)
+    if cluster.kind == "map":
+        # every operand and the output double-buffered, plus one
+        # temporary per body node
+        elem_bytes = widest * (2 * (len(operands) + 1) + len(body))
+        block = _map_block(R, C, _sublanes(narrowest), elem_bytes)
+        if block is None:
+            return DeclineReason(
+                DeclineReason.VMEM,
+                f"no ({_sublanes(narrowest)}, {_LANE})-aligned block of the "
+                f"{R}x{C} body fits {_VMEM_BUDGET} bytes of VMEM",
+            )
+        br, bc = block
+    else:
+        need = widest * int(np.prod(body_shape)) * (len(operands) + len(body))
+        if need > _VMEM_BUDGET:
+            return DeclineReason(
+                DeclineReason.VMEM,
+                f"whole-array reduce block of {list(body_shape)} needs ~{need} "
+                f"bytes of VMEM, over the {_VMEM_BUDGET}-byte budget",
+            )
 
     _counter[0] += 1
     name = f"fused_{cluster.kind}{_counter[0]}_" + "_".join(
@@ -361,13 +414,12 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
     lines = [f"def _kernel({krefs}{', ' if krefs else ''}o_ref):"]
     for slot, _, _ in operands:
         lines.append(f"    {slot} = {slot}_ref[...]")
+    lines += [f"    {l}" for l in body_lines]
     if cluster.kind == "map":
-        lines += [f"    {l}" for l in body_lines]
         lines.append(f"    o_ref[...] = {root_v}")
     else:
         # whole-array block: operands arrive at body_shape already; the
         # reduction's static axes were rendered into the body line itself
-        lines += [f"    {l}" for l in body_lines]
         lines.append(f"    o_ref[...] = jnp.reshape({root_v}, {out2 or out_shape})")
     lines.append("")
 
@@ -381,21 +433,18 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
         e = expr
         if shape != body_shape:
             e = f"jnp.broadcast_to({e}, {body_shape})"
-        if cluster.kind == "map" and (len(body_shape) != 2):
+        if cluster.kind == "map" and len(body_shape) != 2:
             e = f"jnp.reshape({e}, ({R}, {C}))"
-        elif cluster.kind == "map":
-            pass  # already (R, C)
         lines.append(f"        {slot} = {e}")
         call_args.append(slot)
     if cluster.kind == "map":
+        spec = f"pl.BlockSpec(({br}, {bc}), lambda i, j: (i, j))"
         lines += [
             "        out = pl.pallas_call(",
             "            _kernel,",
-            f"            grid=({R // br},),",
-            "            in_specs=[" + ", ".join(
-                f"pl.BlockSpec(({br}, {C}), lambda i: (i, 0))" for _ in operands
-            ) + "],",
-            f"            out_specs=pl.BlockSpec(({br}, {C}), lambda i: (i, 0)),",
+            f"            grid=({-(-R // br)}, {-(-C // bc)}),",
+            "            in_specs=[" + ", ".join(spec for _ in operands) + "],",
+            f"            out_specs={spec},",
             f"            out_shape=jax.ShapeDtypeStruct(({R}, {C}), _out_dtype),",
             "            interpret=interpret,",
             f"            name={name!r},",

@@ -118,7 +118,7 @@ def flash_attention_fwd(
     sm_scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """q: (B, H, Sq, D); k, v: (B, KVH, Skv, D).  Returns (B, H, Sq, D)."""
     B, H, Sq, D = q.shape

@@ -3,11 +3,13 @@
 Each op has three interchangeable implementations selected by
 :func:`set_kernel_mode` (or a per-call ``impl=`` override):
 
-* ``"ref"``              — the pure-jnp oracle (default; what the dry-run
-                            lowers and what CPU smoke tests execute),
+* ``"ref"``              — the pure-jnp oracle (the default off a TPU;
+                            what CPU tests and the dry-run execute),
 * ``"pallas_interpret"`` — the Pallas TPU kernel executed by the Pallas
-                            interpreter (correctness validation on CPU),
-* ``"pallas"``           — the compiled Pallas TPU kernel (real hardware).
+                            interpreter (correctness validation on CPU;
+                            only ever chosen explicitly),
+* ``"pallas"``           — the compiled Pallas TPU kernel (the default on
+                            a TPU backend).
 
 AD: every op is a ``jax.custom_vjp``.  Backward passes recompute from the
 reference formulas (flash-attention/SSD) or run the dedicated Pallas
@@ -51,16 +53,16 @@ def _validate(mode: str) -> str:
 
 
 # ``MYIA_KERNEL_MODE`` (the CI matrix axis: the fast job runs ``ref``, the
-# full job also ``pallas_interpret``) used to be read ONCE at import, so a
-# process that changed the environment afterwards — the serve engine
+# full job also ``pallas_interpret``) is re-read on every query, so a
+# process that changes the environment afterwards — the serve engine
 # flipping modes between workloads, or a test driving the mode matrix
-# in-process — silently kept the stale mode.  The env var is now re-read
-# on every query: a *change* to it takes effect immediately, while an
-# explicit ``set_kernel_mode`` wins until the env var next changes.
+# in-process — never keeps a stale mode: a *change* to it takes effect
+# immediately, while an explicit ``set_kernel_mode`` wins until the env
+# var next changes.  With neither, the mode follows the backend.
 _ENV_SEEN = os.environ.get("MYIA_KERNEL_MODE")
 # validate the RAW value when the var is set: an empty/typo'd CI matrix
 # expansion must fail loudly, not silently green the ref path
-_MODE = _validate("ref" if _ENV_SEEN is None else _ENV_SEEN)
+_MODE: str | None = None if _ENV_SEEN is None else _validate(_ENV_SEEN)
 
 
 def set_kernel_mode(mode: str) -> None:
@@ -71,6 +73,9 @@ def set_kernel_mode(mode: str) -> None:
 
 
 def get_kernel_mode() -> str:
+    """The explicit choice (``set_kernel_mode`` / ``MYIA_KERNEL_MODE``),
+    else ``"pallas"`` on a TPU backend and ``"ref"`` anywhere else — so
+    a chip never silently runs the jnp oracle in place of the kernels."""
     global _MODE, _ENV_SEEN
     env = os.environ.get("MYIA_KERNEL_MODE")
     if env != _ENV_SEEN:
@@ -79,6 +84,8 @@ def get_kernel_mode() -> str:
             # failing on every query instead of raising once and going quiet
             _MODE = _validate(env)
         _ENV_SEEN = env
+    if _MODE is None:
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
     return _MODE
 
 

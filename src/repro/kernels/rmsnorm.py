@@ -44,7 +44,7 @@ def rmsnorm_fwd(
     *,
     eps: float = 1e-6,
     block_rows: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """x: (..., D); w: (D,).  Returns same shape/dtype as x."""
     orig_shape = x.shape
@@ -76,7 +76,7 @@ def rmsnorm_bwd(
     *,
     eps: float = 1e-6,
     block_rows: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (dx, dw) with dx in x.dtype and dw in w.dtype."""
     orig_shape = x.shape
@@ -97,13 +97,15 @@ def rmsnorm_bwd(
         ],
         out_specs=[
             pl.BlockSpec((br, D), lambda i: (i, 0)),
-            pl.BlockSpec((1, D), lambda i: (i, 0)),
+            # (nb, 1, D) with a squeezed leading dim: the block's last two
+            # dims are then the array's own, as Mosaic requires
+            pl.BlockSpec((None, 1, D), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((R, D), x.dtype),
-            jax.ShapeDtypeStruct((nb, D), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, D), jnp.float32),
         ],
         interpret=interpret,
         name="rmsnorm_bwd",
     )(x2, w.reshape(1, D), dy2)
-    return dx.reshape(orig_shape), jnp.sum(dw_part, axis=0).astype(w.dtype)
+    return dx.reshape(orig_shape), jnp.sum(dw_part, axis=(0, 1)).astype(w.dtype)

@@ -27,7 +27,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hT_ref, h_ref, *, chunk: int):
+def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hT_ref, h_ref):
+    h_idx = pl.program_id(1)
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
 
@@ -35,47 +36,59 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hT_ref, h_ref, *, chu
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[0, :, 0].astype(jnp.float32)  # (L, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)  # (L,)
-    a = a_ref[0].astype(jnp.float32)  # ()
-    bm = b_ref[0, :, 0].astype(jnp.float32)  # (L, N)
-    cm = c_ref[0, :, 0].astype(jnp.float32)  # (L, N)
+    x = x_ref[...].astype(jnp.float32)  # (L, P)
+    dt_col = dt_ref[...].astype(jnp.float32)  # (L, 1)
+    a = a_ref[h_idx]  # () from SMEM
+    bm = b_ref[...].astype(jnp.float32)  # (L, N)
+    cm = c_ref[...].astype(jnp.float32)  # (L, N)
 
-    da = a * dt  # (L,) log-decay increments (a < 0)
-    cum = jnp.cumsum(da)  # (L,) inclusive
+    # The chunk's cumulative log-decay, as a column and as a row.  Mosaic
+    # has no cumsum or (L,1)<->(1,L) transpose, so both are masked
+    # reductions over an (L, L) broadcast.
+    L = x.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    diag = rows == cols
+    dt_row = jnp.sum(jnp.where(diag, jnp.broadcast_to(dt_col, (L, L)), 0.0), axis=0, keepdims=True)
+    da_col = a * dt_col  # (L, 1) log-decay increments (a < 0)
+    da_row = a * dt_row  # (1, L)
+    cum_col = jnp.sum(  # (L, 1): inclusive cumsum, cum_i = Σ_{j≤i} da_j
+        jnp.where(cols <= rows, jnp.broadcast_to(da_row, (L, L)), 0.0), axis=1, keepdims=True
+    )
+    cum_row = jnp.sum(  # (1, L): the same values laid out as a row
+        jnp.where(rows <= cols, jnp.broadcast_to(da_col, (L, L)), 0.0), axis=0, keepdims=True
+    )
+    total = jnp.sum(da_row, axis=1, keepdims=True)  # (1, 1) = cum_{L-1}
 
     # -- intra-chunk (dual / attention-like form) ---------------------------
     s = jax.lax.dot_general(
         cm, bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (L, L):  C_i · B_j
-    seg = cum[:, None] - cum[None, :]  # log decay j→i
-    L = x.shape[0]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    seg = cum_col - cum_row  # log decay j→i
     decay = jnp.where(rows >= cols, jnp.exp(seg), 0.0)
-    s = s * decay * dt[None, :]
+    s = s * decay * dt_row
     y = jax.lax.dot_general(
         s, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # (L, P)
 
     # -- inter-chunk: carried state contribution ----------------------------
     h = h_ref[...]  # (N, P) f32
-    y = y + jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y = y + jnp.exp(cum_col) * jax.lax.dot_general(
         cm, h, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
 
     # -- state update --------------------------------------------------------
-    w = jnp.exp(cum[-1] - cum) * dt  # (L,)
-    h_new = jnp.exp(cum[-1]) * h + jax.lax.dot_general(
-        bm * w[:, None], x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    w = jnp.exp(total - cum_col) * dt_col  # (L, 1)
+    h_new = jnp.exp(total) * h + jax.lax.dot_general(
+        bm * w, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # (N, P)
     h_ref[...] = h_new
 
-    y_ref[0, :, 0] = y.astype(y_ref.dtype)
+    y_ref[...] = y.astype(y_ref.dtype)
 
     @pl.when(ci == nc - 1)
     def _emit_state():
-        hT_ref[0, 0] = h_new
+        hT_ref[...] = h_new
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -87,17 +100,27 @@ def ssd_scan_fwd(
     C: jax.Array,
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """x: (Bt,S,H,P); dt: (Bt,S,H); A: (H,); B,C: (Bt,S,G,N).
 
-    Returns (y: (Bt,S,H,P) in x.dtype, final_state: (Bt,H,N,P) f32)."""
+    Returns (y: (Bt,S,H,P) in x.dtype, final_state: (Bt,H,N,P) f32).
+
+    The kernel runs head-major: the wrapper moves the head axis ahead of
+    the sequence so every block is a ``(chunk, P)``/``(chunk, N)`` tile
+    (``chunk`` a multiple of 8, or the whole sequence), ``dt`` travels as
+    a ``(chunk, 1)`` column and ``A`` sits whole in SMEM."""
     Bt, S, H, P = x.shape
     G, N = B.shape[2], B.shape[3]
     assert H % G == 0, (H, G)
     rep = H // G
     L = min(chunk, S)
     assert S % L == 0, (S, L)
+
+    xh = jnp.transpose(x, (0, 2, 1, 3))  # (Bt, H, S, P)
+    dth = jnp.transpose(dt, (0, 2, 1))[..., None]  # (Bt, H, S, 1)
+    Bh = jnp.transpose(B, (0, 2, 1, 3))  # (Bt, G, S, N)
+    Ch = jnp.transpose(C, (0, 2, 1, 3))
 
     grid = (Bt, H, S // L)
     kwargs = {}
@@ -106,26 +129,26 @@ def ssd_scan_fwd(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
     y, hT = pl.pallas_call(
-        functools.partial(_ssd_kernel, chunk=L),
+        _ssd_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, L, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, L, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
-            pl.BlockSpec((1, L, 1, N), lambda b, h, c, r=rep: (b, c, h // r, 0)),
-            pl.BlockSpec((1, L, 1, N), lambda b, h, c, r=rep: (b, c, h // r, 0)),
+            pl.BlockSpec((None, None, L, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((None, None, L, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, None, L, N), lambda b, h, c, r=rep: (b, h // r, c, 0)),
+            pl.BlockSpec((None, None, L, N), lambda b, h, c, r=rep: (b, h // r, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, L, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, 1, N, P), lambda b, h, c: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, L, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((None, None, N, P), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct((Bt, H, S, P), x.dtype),
             jax.ShapeDtypeStruct((Bt, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
         name="ssd_scan_fwd",
         **kwargs,
-    )(x, dt, A, B, C)
-    return y, hT
+    )(xh, dth, A.astype(jnp.float32), Bh, Ch)
+    return jnp.transpose(y, (0, 2, 1, 3)), hT

@@ -137,8 +137,7 @@ def make_myia_train_step(
     vag = api.value_and_grad(
         build_lm_loss(dims, batch, seq),
         wrt=(0, 1, 2, 3),
-        fuse=fuse,
-        in_specs=lm_in_specs(),
+        options=api.CompileOptions(fuse=fuse, in_specs=lm_in_specs()),
     )
 
     @jax.jit

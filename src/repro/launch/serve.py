@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, get_config
+from repro.launch.cache import checkout_path, enable_compile_cache
 from repro.models import decode_step, init_params, prefill
 
 
@@ -68,7 +69,7 @@ def main(argv=None) -> int:
     ap.add_argument("--min-bucket", type=int, default=32)
     ap.add_argument(
         "--cache-dir",
-        default="artifacts/progcache",
+        default=checkout_path("artifacts", "progcache"),
         help="myia: persistent AOT program cache directory ('' disables)",
     )
     ap.add_argument(
@@ -102,6 +103,7 @@ def main(argv=None) -> int:
         "(scrape-file / node_exporter textfile-collector format)",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
 
@@ -295,7 +297,8 @@ def _serve_myia_full_prefix(args, cfg) -> int:
     dims = MyiaLMDims.from_config(cfg)
     params = init_lm_params(dims, jax.random.PRNGKey(0))
     logits_fn = api.myia(
-        build_lm_logits(dims), fuse=True, in_specs=lm_in_specs(with_labels=False)
+        build_lm_logits(dims),
+        options=api.CompileOptions(fuse=True, in_specs=lm_in_specs(with_labels=False)),
     )
 
     rng = np.random.default_rng(0)

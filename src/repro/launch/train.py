@@ -37,6 +37,7 @@ from repro.distributed import (
     make_train_state_fn,
     make_train_step,
 )
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.optim import OptConfig, make_optimizer
 from repro.parallel import mesh_context
@@ -64,6 +65,7 @@ def main(argv=None) -> int:
         "(optimized+fused graph, shard_map under a mesh)",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     ds = SyntheticLM(

@@ -26,34 +26,8 @@ __all__ = [
     "logical_to_spec",
     "DEFAULT_RULES",
     "named_sharding",
-    "abstract_mesh",
-    "shard_map",
 ]
 
-
-def abstract_mesh(axis_sizes, axis_names):
-    """Device-less mesh for structural sharding checks, across jax's
-    ``AbstractMesh`` signature variants: one tuple of ``(name, size)``
-    pairs (e.g. jax 0.4.37) vs. two positionals ``(sizes, names)``."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-
-
-def _shard_map():
-    """``jax.shard_map`` moved between jax versions (experimental →
-    top-level); resolve whichever this jax provides."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm
-
-
-shard_map = _shard_map()
 
 #: logical axis → physical mesh axis (or tuple of axes, or None=replicated).
 #: ``batch`` spans the pure-data axes; model-parallel dims map to "model".

@@ -12,92 +12,6 @@ import numpy as np
 import pytest
 
 
-def _install_hypothesis_stub() -> None:
-    """The container may lack ``hypothesis``; property tests then degrade to
-    deterministic grid sampling over the declared strategy bounds instead of
-    erroring the whole suite at collection.  Only the API surface these tests
-    use is provided (given / settings / floats / integers / sampled_from /
-    booleans)."""
-    try:
-        import hypothesis  # noqa: F401
-
-        return
-    except ModuleNotFoundError:
-        pass
-
-    import functools
-    import inspect
-    import random
-    import types
-
-    class _Strategy:
-        def __init__(self, sampler):
-            self._sampler = sampler  # rng -> value
-
-        def sample(self, rng):
-            return self._sampler(rng)
-
-    def floats(min_value=-1e6, max_value=1e6, allow_nan=None, **_kw):
-        lo, hi = float(min_value), float(max_value)
-        return _Strategy(lambda rng: rng.uniform(lo, hi))
-
-    def integers(min_value=0, max_value=100, **_kw):
-        lo, hi = int(min_value), int(max_value)
-        return _Strategy(lambda rng: rng.randint(lo, hi))
-
-    def sampled_from(elements):
-        pool = list(elements)
-        return _Strategy(lambda rng: pool[rng.randrange(len(pool))])
-
-    def booleans():
-        return _Strategy(lambda rng: bool(rng.randrange(2)))
-
-    def given(*pos_strats, **kw_strats):
-        assert not pos_strats, "stub supports keyword strategies only"
-
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_stub_max_examples", 10)
-                rng = random.Random(0)
-                for _ in range(n):
-                    drawn = {k: s.sample(rng) for k, s in kw_strats.items()}
-                    fn(*args, **drawn, **kwargs)
-
-            # hide the drawn parameters, or pytest treats them as fixtures
-            sig = inspect.signature(fn)
-            wrapper.__signature__ = sig.replace(
-                parameters=[
-                    p for name, p in sig.parameters.items() if name not in kw_strats
-                ]
-            )
-            return wrapper
-
-        return deco
-
-    def settings(max_examples=10, **_kw):
-        def deco(fn):
-            fn._stub_max_examples = max_examples
-            return fn
-
-        return deco
-
-    mod = types.ModuleType("hypothesis")
-    mod.given = given
-    mod.settings = settings
-    st_mod = types.ModuleType("hypothesis.strategies")
-    st_mod.floats = floats
-    st_mod.integers = integers
-    st_mod.sampled_from = sampled_from
-    st_mod.booleans = booleans
-    mod.strategies = st_mod
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = st_mod
-
-
-_install_hypothesis_stub()
-
-
 @pytest.fixture
 def rng():
     return np.random.RandomState(0)

@@ -59,7 +59,14 @@ def _softplusish(x, w):
     return P.reduce_sum(P.log(1.0 + P.exp(h)) * P.sigmoid(h), (0, 1), False)
 
 
+def _masked_tanh(x, y):
+    # gt -> cast -> mul -> add: a map cluster holding a cast, whose dtype
+    # operand must stay a closure-bound constant of the kernel
+    return P.reduce_sum(P.tanh(x) * P.cast(P.gt(x, y), _F32DT) + y, (0, 1), False)
+
+
 _F32 = jax.ShapeDtypeStruct((), jnp.float32)
+_F32DT = np.dtype("float32")
 
 CORPUS = [
     ("grad_cube", build_grad_graph, _cube, 0, (_F32,)),
@@ -80,6 +87,20 @@ CORPUS = [
     ("fwd_reduce_chain", None, _reduce_chain, 0, (jnp.linspace(-2, 2, 32).reshape(4, 8),)),
     ("grad_reduce_chain", build_grad_graph, _reduce_chain, 0,
      (jnp.linspace(-2, 2, 32).reshape(4, 8),)),
+    (
+        "fwd_masked_tanh",
+        None,
+        _masked_tanh,
+        0,
+        (jnp.linspace(-2, 2, 32).reshape(4, 8), jnp.full((4, 8), 0.25)),
+    ),
+    (
+        "grad_masked_tanh",
+        build_grad_graph,
+        _masked_tanh,
+        0,
+        (jnp.linspace(-2, 2, 32).reshape(4, 8), jnp.full((4, 8), 0.25)),
+    ),
     (
         "grad_softplusish",
         build_grad_graph,
@@ -228,6 +249,27 @@ class TestCodegen:
         set_kernel_mode("pallas_interpret")
         r_int = jax.jit(lambda *a: k(*a))(*args)
         np.testing.assert_array_equal(np.asarray(r_ref), np.asarray(r_int))
+
+    @pytest.mark.parametrize(
+        "R,C,sub,elem_bytes",
+        [
+            (2048, 92544, 8, 32),  # internlm2-1.8b logits: columns blocked too
+            (2048, 8192, 8, 40),  # whole rows fit: rows blocked only
+            (5, 300000, 8, 32),  # fewer rows than a tile: the whole R
+            (7, 5, 32, 24),  # small body: one whole-array block
+            (4096, 2048, 16, 24),  # bf16 rows tile by 16
+        ],
+    )
+    def test_map_blocks_are_aligned_and_fit_vmem(self, R, C, sub, elem_bytes):
+        """Mosaic's rule: each block dim is the whole array dim or a
+        multiple of the tiling (sublanes for rows, 128 lanes for
+        columns); and the block fits the VMEM budget."""
+        from repro.kernels import codegen
+
+        br, bc = codegen._map_block(R, C, sub, elem_bytes)
+        assert br == R or (br % sub == 0 and br < R)
+        assert bc == C or (bc % codegen._LANE == 0 and bc < C)
+        assert br * bc * elem_bytes <= codegen._VMEM_BUDGET
 
     def test_scalar_graph_declines_but_lowers(self):
         """An all-opaque graph (scalar adjoint) produces no clusters and

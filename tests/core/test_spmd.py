@@ -235,11 +235,13 @@ class TestMesh1x1Identity:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7)
 
     def test_abstract_mesh_context_does_not_engage_spmd(self):
-        from repro.parallel import abstract_mesh, mesh_context
+        from jax.sharding import AbstractMesh
+
+        from repro.parallel import mesh_context
 
         args = _mlp_args()
         vag = value_and_grad(_two_layer, (0, 1), in_specs=(None, None, ("data",)))
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         with mesh_context(mesh, {}):
             runner = vag.specialize(args)
         assert not getattr(runner, "spmd", False)

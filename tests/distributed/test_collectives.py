@@ -15,10 +15,8 @@ def run_with_devices(body: str, n: int = 8) -> str:
         import sys
         sys.path.insert(0, {os.path.abspath('src')!r})
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import PartitionSpec as P
-        from repro.parallel import shard_map as _sm  # location-compat shim
-        jax.shard_map = _sm
-        mesh = jax.make_mesh(({n},), ("d",))
+        from jax.sharding import AxisType, PartitionSpec as P
+        mesh = jax.make_mesh(({n},), ("d",), axis_types=(AxisType.Auto,))
         """
     ) + textwrap.dedent(body)
     res = subprocess.run(
@@ -121,7 +119,9 @@ class TestShardedTrainStep:
             from repro.optim import OptConfig, make_optimizer
             from repro.parallel import mesh_context
             from repro.data import DataConfig, SyntheticLM
-            mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+            mesh2 = jax.make_mesh(
+                (2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+            )
             cfg = get_config("internlm2-1.8b", reduced=True)
             # warmup_steps=1: the default 100-step warmup leaves lr ≈ 0 for
             # all 8 steps and the decrease assertion would ride on batch noise

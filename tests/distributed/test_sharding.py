@@ -2,20 +2,20 @@
 devices needed: specs are checked structurally."""
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.distributed.sharding import batch_specs, make_rules, param_specs, tree_specs
 from repro.models import init_params
 from repro.optim import OptConfig, make_optimizer
-from repro.parallel import MeshContext, abstract_mesh
+from repro.parallel import MeshContext
 
 
 def ctx_for(cfg, multi=False):
     mesh = (
-        abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+        AbstractMesh((2, 16, 16), ("pod", "data", "model"))
         if multi
-        else abstract_mesh((16, 16), ("data", "model"))
+        else AbstractMesh((16, 16), ("data", "model"))
     )
     return MeshContext(mesh, make_rules(cfg))
 

@@ -32,7 +32,7 @@ class TestForward:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_matches_ref(self, causal, dtype):
         q, k, v = make_qkv(0, 2, 4, 2, 128, 128, 64, dtype)
-        out = flash_attention_fwd(q, k, v, causal=causal, block_q=64, block_k=64)
+        out = flash_attention_fwd(q, k, v, causal=causal, block_q=64, block_k=64, interpret=True)
         exp = ref.flash_attention_ref(q, k, v, causal=causal)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(exp, np.float32), **tol(dtype)
@@ -40,29 +40,35 @@ class TestForward:
 
     def test_sliding_window(self):
         q, k, v = make_qkv(1, 1, 2, 2, 256, 256, 32)
-        out = flash_attention_fwd(q, k, v, causal=True, window=64, block_q=64, block_k=64)
+        out = flash_attention_fwd(
+            q, k, v, causal=True, window=64, block_q=64, block_k=64, interpret=True
+        )
         exp = ref.flash_attention_ref(q, k, v, causal=True, window=64)
         np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=2e-5, atol=2e-5)
 
     def test_gqa_equals_repeated_mha(self):
         """GQA via index_map == physically repeating the kv heads."""
         q, k, v = make_qkv(2, 1, 8, 2, 64, 64, 32)
-        out = flash_attention_fwd(q, k, v, causal=True, block_q=32, block_k=32)
+        out = flash_attention_fwd(q, k, v, causal=True, block_q=32, block_k=32, interpret=True)
         k_rep = jnp.repeat(k, 4, axis=1)
         v_rep = jnp.repeat(v, 4, axis=1)
-        exp = flash_attention_fwd(q, k_rep, v_rep, causal=True, block_q=32, block_k=32)
+        exp = flash_attention_fwd(
+            q, k_rep, v_rep, causal=True, block_q=32, block_k=32, interpret=True
+        )
         np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=1e-6)
 
     def test_window_wider_than_seq_is_noop(self):
         q, k, v = make_qkv(3, 1, 2, 1, 64, 64, 32)
-        out = flash_attention_fwd(q, k, v, causal=True, window=4096, block_q=32, block_k=32)
-        exp = flash_attention_fwd(q, k, v, causal=True, block_q=32, block_k=32)
+        out = flash_attention_fwd(
+            q, k, v, causal=True, window=4096, block_q=32, block_k=32, interpret=True
+        )
+        exp = flash_attention_fwd(q, k, v, causal=True, block_q=32, block_k=32, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=1e-6)
 
     def test_cross_attention_no_mask(self):
         """Sq != Skv, no causal mask (encoder-decoder cross-attention)."""
         q, k, v = make_qkv(4, 2, 4, 4, 64, 128, 32)
-        out = flash_attention_fwd(q, k, v, block_q=32, block_k=64)
+        out = flash_attention_fwd(q, k, v, block_q=32, block_k=64, interpret=True)
         exp = ref.flash_attention_ref(q, k, v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=2e-5, atol=2e-5)
 
@@ -83,7 +89,7 @@ class TestForward:
         if causal and Sq != Skv:
             Skv = Sq  # causal mask defined for square layouts in this kernel
         q, k, v = make_qkv(seed, B, H, KVH, Sq, Skv, D)
-        out = flash_attention_fwd(q, k, v, causal=causal, block_q=bq, block_k=bk)
+        out = flash_attention_fwd(q, k, v, causal=causal, block_q=bq, block_k=bk, interpret=True)
         exp = ref.flash_attention_ref(q, k, v, causal=causal)
         np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=3e-5, atol=3e-5)
 

@@ -22,7 +22,7 @@ class TestForward:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_matches_ref(self, dtype):
         x, w = make(0, 512, 256, dtype)
-        out = rmsnorm_fwd(x, w, block_rows=128)
+        out = rmsnorm_fwd(x, w, block_rows=128, interpret=True)
         exp = ref.rmsnorm_ref(x, w)
         tol = 2e-2 if dtype == jnp.bfloat16 else 1e-6
         np.testing.assert_allclose(
@@ -33,7 +33,7 @@ class TestForward:
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 32, 128))
         w = jnp.ones((128,))
         np.testing.assert_allclose(
-            np.asarray(rmsnorm_fwd(x, w, block_rows=64)),
+            np.asarray(rmsnorm_fwd(x, w, block_rows=64, interpret=True)),
             np.asarray(ref.rmsnorm_ref(x, w)),
             rtol=1e-6,
             atol=1e-6,
@@ -48,7 +48,7 @@ class TestForward:
     )
     def test_property_sweep(self, seed, R, D, br):
         x, w = make(seed, R, D)
-        out = rmsnorm_fwd(x, w, block_rows=br)
+        out = rmsnorm_fwd(x, w, block_rows=br, interpret=True)
         exp = ref.rmsnorm_ref(x, w)
         np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=1e-5, atol=1e-5)
 
@@ -57,7 +57,7 @@ class TestBackward:
     def test_bwd_kernel_matches_jax_grad(self):
         x, w = make(2, 256, 128)
         dy = jax.random.normal(jax.random.PRNGKey(3), x.shape)
-        dx, dw = rmsnorm_bwd(x, w, dy, block_rows=64)
+        dx, dw = rmsnorm_bwd(x, w, dy, block_rows=64, interpret=True)
         (ex, ew) = jax.grad(
             lambda x_, w_: jnp.sum(ref.rmsnorm_ref(x_, w_) * dy), argnums=(0, 1)
         )(x, w)

@@ -25,7 +25,7 @@ class TestForward:
     @pytest.mark.parametrize("chunk", [16, 32, 64])
     def test_matches_stepwise_ref(self, chunk):
         x, dt, A, B, C = make(0, 2, 64, 4, 16, 2, 32)
-        y, hT = ssd_scan_fwd(x, dt, A, B, C, chunk=chunk)
+        y, hT = ssd_scan_fwd(x, dt, A, B, C, chunk=chunk, interpret=True)
         ye, he = ref.ssd_scan_ref(x, dt, A, B, C)
         np.testing.assert_allclose(np.asarray(y), np.asarray(ye), rtol=2e-4, atol=2e-4)
         # kernel state is (N,P); ref state is (H,N,P) — same layout here
@@ -33,8 +33,8 @@ class TestForward:
 
     def test_single_chunk_equals_full(self):
         x, dt, A, B, C = make(1, 1, 32, 2, 8, 1, 16)
-        y1, h1 = ssd_scan_fwd(x, dt, A, B, C, chunk=32)
-        y2, h2 = ssd_scan_fwd(x, dt, A, B, C, chunk=8)
+        y1, h1 = ssd_scan_fwd(x, dt, A, B, C, chunk=32, interpret=True)
+        y2, h2 = ssd_scan_fwd(x, dt, A, B, C, chunk=8, interpret=True)
         np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=2e-4, atol=2e-4)
         np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=2e-4, atol=2e-4)
 
@@ -51,7 +51,7 @@ class TestForward:
     def test_property_sweep(self, seed, Bt, S, HG, P, N, chunk):
         H, G = HG
         x, dt, A, B, C = make(seed, Bt, S, H, P, G, N)
-        y, hT = ssd_scan_fwd(x, dt, A, B, C, chunk=chunk)
+        y, hT = ssd_scan_fwd(x, dt, A, B, C, chunk=chunk, interpret=True)
         ye, he = ref.ssd_scan_ref(x, dt, A, B, C)
         np.testing.assert_allclose(np.asarray(y), np.asarray(ye), rtol=5e-4, atol=5e-4)
         np.testing.assert_allclose(np.asarray(hT), np.asarray(he), rtol=5e-4, atol=5e-4)

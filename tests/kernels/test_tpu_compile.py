@@ -1,0 +1,129 @@
+"""The main path's Pallas kernels compile for a TPU v5e at real widths.
+
+Nothing runs: each kernel is compiled from ``ShapeDtypeStruct``s for a
+*described* ``v5e:2x2`` topology, which catches what interpret mode
+cannot — blocks not aligned to the tiling, VMEM overruns.  The topology
+is described inside a module fixture (never at import), so only the
+worker that runs this file loads the TPU compiler, and every worker
+collects the same tests.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core.fusion import DeclineReason, partition_graph
+from repro.kernels.codegen import emit_cluster_explained
+from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.rmsnorm import rmsnorm_bwd, rmsnorm_fwd
+from repro.kernels.ssd_scan import ssd_scan_fwd
+from repro.launch.myia_step import MyiaLMDims, make_myia_train_step
+
+_KERNEL = 'custom_call_target="tpu_custom_call"'
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler to describe the chip with
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-chip compile can be written to the persistent cache but
+    # never read back without a chip: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return make
+
+
+def _compiled_text(fn, *avals) -> str:
+    return jax.jit(fn).lower(*avals).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def lm_clusters(sds):
+    """The fusion clusters of the LM loss+gradient at internlm2-1.8b
+    widths, batch 8 x seq 256, each with its codegen verdict."""
+    dims = MyiaLMDims.from_config(get_config("internlm2-1.8b"))
+    B, S = 8, 256
+    vag = make_myia_train_step(dims, B, S, lr=0.1, fuse=True)[0].vag
+    V, D, H = dims.vocab, dims.d_model, dims.d_hidden
+    args = (
+        sds((V, D)), sds((D, H)), sds((H, D)), sds((D, V)),
+        sds((B, S), jnp.int32), sds((B, S), jnp.int32),
+    )
+    g = vag.optimized_graph(*args)
+    return [(c, *emit_cluster_explained(c)) for c in partition_graph(g).clusters]
+
+
+def test_lm_train_step_kernels(lm_clusters, sds):
+    kernels = [(c, k) for c, k, _ in lm_clusters if k is not None]
+    assert len(kernels) >= 3
+    for cluster, kernel in kernels:
+        avals = [sds(n.abstract.shape, n.abstract.dtype) for n in cluster.inputs]
+        assert _KERNEL in _compiled_text(kernel.pallas_compiled, *avals), kernel.name
+
+
+def test_lm_reduce_cluster_over_vmem_declines(lm_clusters):
+    """The cross-entropy sum reduces a (8, 256, 92544) body: its
+    whole-array block cannot fit VMEM, so codegen declines it instead of
+    emitting a kernel Mosaic would refuse."""
+    reasons = [r for c, _, r in lm_clusters if c.kind == "reduce"]
+    assert reasons and all(r.kind == DeclineReason.VMEM for r in reasons)
+
+
+def test_flash_attention_fwd(sds):
+    q = sds((1, 16, 4096, 128), jnp.bfloat16)
+    kv = sds((1, 8, 4096, 128), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return flash_attention_fwd(q, k, v, causal=True, interpret=False)
+
+    assert _KERNEL in _compiled_text(fwd, q, kv, kv)
+
+
+def test_rmsnorm_fwd(sds):
+    def fwd(x, w):
+        return rmsnorm_fwd(x, w, interpret=False)
+
+    assert _KERNEL in _compiled_text(fwd, sds((8192, 2048)), sds((2048,)))
+
+
+def test_rmsnorm_bwd(sds):
+    def bwd(x, w, dy):
+        return rmsnorm_bwd(x, w, dy, interpret=False)
+
+    x = sds((8192, 2048))
+    assert _KERNEL in _compiled_text(bwd, x, sds((2048,)), x)
+
+
+def test_ssd_scan_fwd_mamba2_370m(sds):
+    """mamba2-370m: d_inner 2048 = 32 heads of 64, state 128, one group."""
+    cfg = get_config("mamba2-370m")
+    H, P, N, S = cfg.n_ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, 4096
+
+    def fwd(x, dt, A, B, C):
+        return ssd_scan_fwd(x, dt, A, B, C, interpret=False)
+
+    text = _compiled_text(
+        fwd,
+        sds((1, S, H, P)), sds((1, S, H)), sds((H,)),
+        sds((1, S, 1, N)), sds((1, S, 1, N)),
+    )
+    assert _KERNEL in text

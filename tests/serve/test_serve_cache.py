@@ -80,6 +80,33 @@ def test_aot_runner_survives_tracer_args_after_eager_call(tmp_path):
     )
 
 
+def test_other_device_kind_misses(tmp_path, monkeypatch):
+    """An executable compiled for one device kind is never loaded on
+    another: the kind is part of the key, so the lookup misses and the
+    program compiles afresh."""
+    from repro.core import api
+    from repro.core import jax_backend
+
+    f = _tiny_fn()
+    x = np.ones((4, 8), np.float32)
+    w = np.full((8, 8), 0.1, np.float32)
+    opts = api.CompileOptions(program_cache=ProgramCache(str(tmp_path)))
+    api.myia(f, options=opts)(x, w)  # cold: compiles and persists
+
+    platform, kind = jax_backend._device_identity()
+    warm = ProgramCache(str(tmp_path))
+    api.myia(f, options=api.CompileOptions(program_cache=warm))(x, w)
+    assert (warm.stats.hits, warm.stats.misses) == (1, 0)
+
+    monkeypatch.setattr(
+        jax_backend, "_device_identity", lambda: (platform, kind + " (other)")
+    )
+    other = ProgramCache(str(tmp_path))
+    api.myia(f, options=api.CompileOptions(program_cache=other))(x, w)
+    assert (other.stats.hits, other.stats.misses) == (0, 1)
+    assert other.stats.xla_compiles == 1
+
+
 def test_cache_spills_when_over_capacity(tmp_path):
     cache = ProgramCache(str(tmp_path), max_entries=1)
     engine = ServeEngine(DIMS, PARAMS, n_slots=2, min_bucket=16, program_cache=cache)
