@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.core import api
 import repro.core.primitives as P
+from repro.obs import trace as obs_trace
 
 __all__ = [
     "MyiaLMDims",
@@ -147,13 +148,14 @@ def make_myia_train_step(
         return new_params, gnorm
 
     def step_fn(state, batch_dict):
-        params = state["params"]
-        loss, grads = vag(*params, batch_dict["tokens"], batch_dict["labels"])
-        new_params, gnorm = _update(params, grads)
-        return (
-            {"params": new_params, "step": state["step"] + 1},
-            {"loss": loss, "gnorm": gnorm},
-        )
+        with obs_trace.span("train.step"):
+            params = state["params"]
+            with obs_trace.span("train.vag"):
+                loss, grads = vag(*params, batch_dict["tokens"], batch_dict["labels"])
+            with obs_trace.span("train.update"):
+                new_params, gnorm = _update(params, grads)
+                step = state["step"] + 1
+        return {"params": new_params, "step": step}, {"loss": loss, "gnorm": gnorm}
 
     def init_fn(rng=None):
         rng = jax.random.PRNGKey(0) if rng is None else rng

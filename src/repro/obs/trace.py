@@ -28,6 +28,18 @@ Design rules (same pattern as ``repro.serve.faults``):
   (drops counted in ``dropped``, peak occupancy in ``high_water``), so an
   armed long-running server cannot leak memory through its telemetry.
 
+* **one clock with the device trace** — an armed span also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so while a profiler
+  session runs the program's spans sit on the ``/host:CPU`` plane beside
+  the device ops (with no session the annotation is a flag check).
+* **what the program does not call itself** — while a tracer is armed,
+  JAX's own compile events become ``jit.trace`` / ``jit.lower`` spans and
+  a ``jit.compile`` or, on a persistent-cache hit, ``jit.cache_load``
+  span (a compile that then writes the cache gets a child
+  ``jit.cache_write``), and each garbage collection a ``host.gc`` span.
+  The hooks are installed when the first ``tracing()`` block arms and
+  removed when the last one exits.
+
 Span taxonomy: see ``docs/observability.md`` for the full table mapping
 each pipeline stage to its span name.
 """
@@ -35,6 +47,7 @@ each pipeline stage to its span name.
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import threading
 import time
@@ -78,6 +91,21 @@ SPAN_NAMES = frozenset({
     "lower",
     "xla.compile",
     "xla.tier0_compile",
+    # JAX's own compile events, recorded by the armed tracer's listener:
+    # a backend compile, or a load from the persistent cache on a hit; a
+    # compile's write of its executable to the cache nests in it
+    "jit.trace",
+    "jit.lower",
+    "jit.compile",
+    "jit.cache_load",
+    "jit.cache_write",
+    # one train step of ``launch/myia_step.step_fn``: the Myia
+    # loss+gradient call, then the SGD update and the step-counter add
+    "train.step",
+    "train.vag",
+    "train.update",
+    # a garbage collection of the host's Python runtime (``gc.callbacks``)
+    "host.gc",
     # cache tiers (AOT executables + optimized graphs)
     "cache.lookup",
     "cache.write",
@@ -99,6 +127,18 @@ MARK_NAMES = frozenset({
     "serve.first_token",
     "serve.terminal",
 })
+
+#: JAX's compile time-span events (``jax._src.dispatch``) -> span names
+JAX_SPAN_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+}
+#: JAX's persistent-cache events, fired inside ``backend_compile_duration``:
+#: a hit, and a miss's compiled executable about to be written (JAX 0.9
+#: counts a miss as the entry is written, not when the lookup fails)
+JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+JAX_CACHE_WRITE = "/jax/compilation_cache/cache_misses"
 
 
 class SpanRecord:
@@ -146,11 +186,12 @@ class _LiveSpan:
     — on normal exit or on raise (the exception type lands in the record's
     ``error`` attr and propagates)."""
 
-    __slots__ = ("_tracer", "_rec")
+    __slots__ = ("_tracer", "_rec", "_note")
 
     def __init__(self, tracer: "Tracer", rec: SpanRecord) -> None:
         self._tracer = tracer
         self._rec = rec
+        self._note = None
 
     def set(self, **attrs: Any) -> "_LiveSpan":
         """Attach attributes discovered mid-span (counts, cache verdicts)."""
@@ -164,11 +205,13 @@ class _LiveSpan:
         return self._rec.dur_s
 
     def __enter__(self) -> "_LiveSpan":
+        self._note = _annotation(self._rec.name)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         rec = self._rec
         rec.t1 = time.monotonic()
+        self._note.__exit__(None, None, None)
         if exc_type is not None:
             rec.attrs["error"] = exc_type.__name__
         self._tracer._close(rec)
@@ -225,6 +268,15 @@ class Tracer:
 
     def _close(self, rec: SpanRecord) -> None:
         self._depth.d = max(getattr(self._depth, "d", 1) - 1, 0)
+        self._append(rec)
+
+    def span_at(self, name: str, t0: float, t1: float, attrs: dict, below: int = 0) -> None:
+        """Record a closed span whose interval was measured elsewhere (a
+        JAX compile event, a garbage collection), nested at the calling
+        thread's current depth, or ``below`` levels deeper."""
+        depth = getattr(self._depth, "d", 0) + below
+        rec = SpanRecord(name, t0, depth, threading.get_ident(), attrs)
+        rec.t1 = t1
         self._append(rec)
 
     def mark(self, name: str, attrs: dict, ts: float | None = None) -> None:
@@ -376,19 +428,125 @@ def active() -> Tracer | None:
     return _ACTIVE
 
 
+#: the tracers of the open ``tracing()`` blocks, innermost last
+_ARMED: list[Tracer] = []
+_ARM_LOCK = threading.Lock()
+
+
 @contextlib.contextmanager
 def tracing(tracer: Tracer | None):
     """Arm ``tracer`` process-wide for the dynamic extent of the block.
     ``tracing(None)`` is a no-op block, so call sites can thread an
-    optional tracer without branching."""
-    global _ACTIVE
-    prev = _ACTIVE
-    if tracer is not None:
-        _ACTIVE = tracer
+    optional tracer without branching.  The innermost open block's tracer
+    is the armed one, whichever order blocks on several threads exit in.
+    The first block to arm installs the JAX compile listeners and the
+    ``gc`` hook, and the last one to exit removes them."""
+    if tracer is None:
+        yield None
+        return
+    _arm(tracer)
     try:
         yield tracer
     finally:
-        _ACTIVE = prev
+        _disarm(tracer)
+
+
+def _arm(tracer: Tracer) -> None:
+    global _ACTIVE
+    with _ARM_LOCK:
+        if not _ARMED:
+            _hooks.install()
+        _ARMED.append(tracer)
+        _ACTIVE = tracer
+
+
+def _disarm(tracer: Tracer) -> None:
+    global _ACTIVE
+    with _ARM_LOCK:
+        i = max(i for i, t in enumerate(_ARMED) if t is tracer)
+        del _ARMED[i]
+        _ACTIVE = _ARMED[-1] if _ARMED else None
+        if not _ARMED:
+            _hooks.remove()
+
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` of ``name``: the span on
+    the profiler's ``/host:CPU`` plane (jax imported lazily, armed only)."""
+    from jax.profiler import TraceAnnotation
+
+    note = TraceAnnotation(name)
+    note.__enter__()
+    return note
+
+
+class _Hooks:
+    """JAX's compile events and the interpreter's garbage collections,
+    turned into spans on the armed tracer.  A collection is also bridged
+    to the profiler from its start to its stop callback."""
+
+    def __init__(self) -> None:
+        self._local = threading.local()  # per thread: the last cache verdict
+        self._gc: tuple[float, Any] | None = None  # the collection in progress
+
+    def install(self) -> None:
+        from jax import monitoring
+
+        self._gc = None
+        monitoring.register_event_time_span_listener(self._on_jax_span)
+        monitoring.register_event_listener(self._on_jax_event)
+        gc.callbacks.append(self._on_gc)
+
+    def remove(self) -> None:
+        from jax import monitoring
+
+        monitoring.unregister_event_time_span_listener(self._on_jax_span)
+        monitoring.unregister_event_listener(self._on_jax_event)
+        gc.callbacks.remove(self._on_gc)
+
+    # -- JAX: ``record_event_time_span(event, start, end)`` as each compile
+    #    step ends, in ``time.time()``; cache verdicts fire within ----------
+    def _on_jax_event(self, event: str, **kwargs: Any) -> None:
+        if event == JAX_CACHE_HIT or event == JAX_CACHE_WRITE:
+            self._local.verdict = (event, time.time())
+
+    def _on_jax_span(self, event: str, start: float, end: float, **kwargs: Any) -> None:
+        name = JAX_SPAN_EVENTS.get(event)
+        t = _ACTIVE
+        if name is None or t is None:
+            return
+        shift = time.monotonic() - time.time()
+        attrs = {"fun_name": kwargs.get("fun_name")}
+        if name == "jit.compile":
+            verdict = getattr(self._local, "verdict", None)
+            self._local.verdict = None
+            if verdict is not None and start <= verdict[1] <= end:
+                if verdict[0] == JAX_CACHE_HIT:
+                    name = "jit.cache_load"
+                else:
+                    write = (verdict[1] + shift, end + shift)
+                    t.span_at("jit.cache_write", *write, dict(attrs), below=1)
+        t.span_at(name, start + shift, end + shift, attrs)
+
+    # -- gc: ``callback("start" | "stop", info)`` around each collection ----
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._gc = (time.monotonic(), _annotation("host.gc"))
+            return
+        if self._gc is None:
+            return
+        (t0, note), self._gc = self._gc, None
+        t1 = time.monotonic()
+        note.__exit__(None, None, None)
+        t = _ACTIVE
+        if t is not None:
+            t.span_at(
+                "host.gc", t0, t1,
+                {"generation": info["generation"], "collected": info["collected"]},
+            )
+
+
+_hooks = _Hooks()
 
 
 def span(name: str, **attrs: Any):

@@ -117,6 +117,9 @@ def test_disarmed_overhead_is_one_global_read():
     s1 = T.span("anything", big_attr="ignored")
     s2 = T.span("other")
     assert s1 is T.NULL_SPAN and s2 is T.NULL_SPAN
+    # the train step's spans take the same path
+    for name in ("train.step", "train.vag", "train.update"):
+        assert T.span(name) is T.NULL_SPAN
     with s1:
         s1.set(x=1)  # all no-ops
     assert s1.dur_s == 0.0
@@ -185,8 +188,209 @@ def test_span_name_registry_covers_instrumented_sources():
 def test_registry_contains_pipeline_and_profiler_names():
     for name in ("compile_pipeline", "optimize", "fuse.partition", "explain.report"):
         assert name in T.SPAN_NAMES
+    # the spans the armed tracer records from JAX's events and gc, too
+    assert set(T.JAX_SPAN_EVENTS.values()) | {"host.gc"} <= T.SPAN_NAMES
     for name in ("serve.submit", "serve.terminal"):
         assert name in T.MARK_NAMES
+
+
+# ---------------------------------------------------------------------------
+# The profiler bridge, JAX's compile events and gc pauses
+# ---------------------------------------------------------------------------
+
+
+def _hooks_installed() -> tuple:
+    import gc
+
+    from jax._src import monitoring
+
+    return (
+        list(gc.callbacks),
+        monitoring.get_scalar_listeners(),
+        monitoring.get_event_time_span_listeners(),
+        monitoring.get_event_listeners(),
+    )
+
+
+def _host_events(log_dir) -> list[str]:
+    """Names of the events on the profiler's ``/host:CPU`` plane."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(str(log_dir / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    data = ProfileData.from_file(path)
+    return [
+        e.name
+        for plane in data.planes
+        if plane.name == "/host:CPU"
+        for line in plane.lines
+        for e in line.events
+    ]
+
+
+def test_armed_spans_are_on_the_profiler_timeline(tmp_path):
+    import gc
+
+    import jax
+
+    tr = T.Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with T.span("train.update"):  # disarmed: never on the timeline
+            pass
+        with T.tracing(tr):
+            with T.span("train.step"):
+                with T.span("train.vag"):
+                    pass
+            gc.collect()
+    names = _host_events(tmp_path)
+    assert {"train.step", "train.vag", "host.gc"} <= set(names)
+    assert "train.update" not in names
+
+
+def test_fresh_jit_records_compile_spans_inside_the_enclosing_span():
+    import jax
+    import jax.numpy as jnp
+
+    def fresh(x):  # a new function object: nothing cached for it
+        return jnp.tanh(x) * 3.0 + 1.0
+
+    tr = T.Tracer()
+    with T.tracing(tr):
+        with T.span("train.vag"):
+            jax.block_until_ready(jax.jit(fresh)(jnp.ones((4, 4))))
+    (outer,) = tr.find("train.vag")
+    for name in ("jit.trace", "jit.lower", "jit.compile"):
+        mine = [e for e in tr.find(name) if "fresh" in e.attrs["fun_name"]]
+        assert len(mine) == 1, name
+        (rec,) = mine
+        assert outer.t0 <= rec.t0 <= rec.t1 <= outer.t1
+        assert rec.depth == outer.depth + 1
+    # no persistent cache here: compiled, with no lookup and no load
+    assert not [e for e in tr.events if e.name in ("jit.cache_load", "jit.cache_write")]
+
+
+def test_disarm_restores_the_hooks_and_records_nothing():
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+
+    before = _hooks_installed()
+    tr = T.Tracer()
+    with T.tracing(tr):
+        with T.tracing(T.Tracer()):  # nested: installed once
+            assert len(gc.callbacks) == len(before[0]) + 1
+        assert len(gc.callbacks) == len(before[0]) + 1
+    assert _hooks_installed() == before
+    n = len(tr.events)
+
+    def later(x):
+        return jnp.cos(x) - 2.0
+
+    jax.block_until_ready(jax.jit(later)(jnp.ones(3)))
+    gc.collect()
+    assert len(tr.events) == n
+
+
+def test_hooks_are_removed_when_the_block_raises():
+    before = _hooks_installed()
+    with pytest.raises(RuntimeError):
+        with T.tracing(T.Tracer()):
+            raise RuntimeError("x")
+    assert _hooks_installed() == before
+
+
+def test_gc_collect_records_a_host_gc_span():
+    import gc
+
+    tr = T.Tracer()
+    with T.tracing(tr):
+        with T.span("train.step"):
+            gc.collect()
+    (step,) = tr.find("train.step")
+    gcs = [e for e in tr.find("host.gc") if e.attrs["generation"] == 2]
+    assert gcs, [e.attrs for e in tr.find("host.gc")]
+    rec = gcs[-1]
+    assert step.t0 <= rec.t0 <= rec.t1 <= step.t1
+    assert rec.depth == step.depth + 1 and rec.attrs["collected"] >= 0
+
+
+@pytest.mark.parametrize("event,name", [
+    ("/jax/compilation_cache/cache_hits", "jit.cache_load"),
+    ("/jax/compilation_cache/cache_misses", "jit.compile"),
+])
+def test_cache_events_mark_the_compile_that_encloses_them(event, name):
+    import time
+
+    from jax import monitoring
+
+    compile_event = "/jax/core/compile/backend_compile_duration"
+    tr = T.Tracer()
+    with T.tracing(tr):
+        with T.span("train.vag"):
+            for _ in range(2):
+                start = time.time()
+                monitoring.record_event(event)
+                monitoring.record_event_time_span(
+                    compile_event, start, time.time(), fun_name="jit(f)"
+                )
+            # a compile with no cache event in it: no cache in use
+            start = time.time()
+            monitoring.record_event_time_span(compile_event, start, time.time(), fun_name="g")
+    compiles = [e for e in tr.events if e.name in ("jit.compile", "jit.cache_load")]
+    assert [(e.name, e.attrs["fun_name"]) for e in compiles] == [
+        (name, "jit(f)"), (name, "jit(f)"), ("jit.compile", "g")
+    ]
+    writes = tr.find("jit.cache_write")
+    if name == "jit.cache_load":
+        assert writes == []
+    else:  # the write that follows the miss nests in its compile, to its end
+        assert len(writes) == 2
+        for write, comp in zip(writes, compiles):
+            assert comp.t0 <= write.t0 <= write.t1 == comp.t1
+            assert write.depth == comp.depth + 1 and write.attrs["fun_name"] == "jit(f)"
+    (vag,) = tr.find("train.vag")
+    assert all(e.depth == vag.depth + 1 for e in compiles)
+
+
+def test_blocks_exiting_out_of_order_leave_nothing_armed():
+    """Two threads' blocks exit in the order they armed, not the reverse:
+    the one still open stays armed, and the last exit disarms all."""
+    before = _hooks_installed()
+    first, second = T.Tracer(), T.Tracer()
+    a, b = T.tracing(first), T.tracing(second)
+    a.__enter__()
+    b.__enter__()
+    assert T.active() is second
+    a.__exit__(None, None, None)
+    assert T.active() is second and _hooks_installed() != before
+    b.__exit__(None, None, None)
+    assert T.active() is None and _hooks_installed() == before
+
+
+def test_train_step_spans_nest():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.launch.myia_step import MyiaLMDims, make_myia_train_step
+
+    dims = MyiaLMDims(32, 8, 16)
+    step_fn, init_fn = make_myia_train_step(dims, 2, 4, 0.1, fuse=False)
+    state = init_fn(jax.random.PRNGKey(1))
+    batch = {"tokens": jnp.zeros((2, 4), jnp.int32), "labels": jnp.ones((2, 4), jnp.int32)}
+    tr = T.Tracer()
+    with T.tracing(tr):
+        state, _ = step_fn(state, batch)
+    (step,) = tr.find("train.step")
+    for name in ("train.vag", "train.update"):
+        (child,) = tr.find(name)
+        assert step.t0 <= child.t0 <= child.t1 <= step.t1
+        assert child.depth == step.depth + 1
+    assert int(state["step"]) == 1
+    # the first call compiled inside the loss+gradient span
+    (vag,) = tr.find("train.vag")
+    assert any(vag.t0 <= e.t0 <= e.t1 <= vag.t1 for e in tr.find("jit.compile"))
 
 
 def test_concurrent_append_exact_drop_accounting():
@@ -235,4 +439,8 @@ def test_concurrent_spans_under_capacity_lose_nothing():
         t.join()
     assert len(tr.find("concurrent")) == n_threads * per_thread
     assert tr.dropped == 0
-    assert tr.high_water == n_threads * per_thread
+    # the armed tracer also records the collections that ran meanwhile
+    assert tr.high_water == len(tr.events) == n_threads * per_thread + len(tr.find("host.gc"))
+    # however the threads' blocks interleaved, the last exit disarmed
+    assert T.active() is None
+
