@@ -152,8 +152,14 @@ def lower_graph(graph: Graph, *, fuse: bool = False, profile: bool = False) -> C
     blockers = lowering_blockers(graph)
     if blockers:
         raise LoweringError("; ".join(blockers))
-    with obs_trace.span("lower", graph=graph.name, fuse=fuse):
-        return _lower_graph_body(graph, fuse, profile)
+    with obs_trace.span("lower", graph=graph.name, fuse=fuse) as sp:
+        fn = _lower_graph_body(graph, fuse, profile)
+        kernels = fn.__fused_kernels__
+        sp.set(
+            broadcast_in_kernel=sum(k.broadcast_in_kernel for k in kernels),
+            broadcast_materialized=sum(k.broadcast_materialized for k in kernels),
+        )
+        return fn
 
 
 def _lower_graph_body(graph: Graph, fuse: bool, profile: bool = False) -> Callable:

@@ -26,10 +26,19 @@ and sized to a VMEM budget, :data:`_VMEM_BUDGET`):
   32-bit type, 16 of a 16-bit one, 32 of an 8-bit one), ``bc`` of the
   128-lane width — so edge blocks may be partial, which elementwise
   bodies tolerate.  Whole rows are kept when 8 of them fit the budget;
-  wider rows are blocked in columns too.  Every operand is materialized
-  *at* ``S`` by the wrapper (broadcast members run there; smaller
-  external inputs are ``broadcast_to``-ed), so the kernel body is pure
-  per-block elementwise code.
+  wider rows are blocked in columns too.  An operand smaller than ``S``
+  that broadcasts along whole rows or columns of the ``(R, C)`` frame —
+  a **row** ``(R, 1)``, a **lane** ``(1, C)`` or a **scalar** ``(1, 1)``,
+  read at the body's rank after ``unreduce``'s keepdims expansion and
+  numpy left-padding — enters the kernel at its own size, with a
+  ``(br, 1)``, ``(1, bc)`` or ``(1, 1)`` block, and the kernel
+  broadcasts it to ``(br, bc)`` in VMEM (a scalar narrower than 32 bits
+  enters as a ``(1, C)`` lane, which Mosaic can broadcast); a broadcast
+  member (``unreduce`` / ``broadcast_to``) of such an operand then runs
+  in the kernel.  Any other smaller operand (a broadcast over a middle
+  axis) is materialized *at* ``S`` by the wrapper (its broadcast member
+  runs there, or it is ``broadcast_to``-ed), so the body is per-block
+  elementwise code either way.
 * **reduce clusters** (reduction root): one whole-array block (no grid)
   — the kernel computes the elementwise body and applies the reduction
   primitive with its static axes, so the floating-point reduction order
@@ -63,6 +72,7 @@ from jax.experimental import pallas as pl
 from repro.core.fusion import Cluster, DeclineReason, classify
 from repro.core.infer import AArray
 from repro.core.ir import Apply, Constant, Node
+from repro.core.primitives import _norm_axes
 from repro.obs import profile as obs_profile
 from .ops import get_kernel_mode
 
@@ -89,6 +99,8 @@ class FusedKernel:
         "body_shape",
         "out_shape",
         "bytes_moved",
+        "broadcast_in_kernel",
+        "broadcast_materialized",
         "oracle",
         "pallas_interpret",
         "pallas_compiled",
@@ -106,6 +118,8 @@ class FusedKernel:
         pallas_interpret: Callable,
         pallas_compiled: Callable,
         bytes_moved: int = 0,
+        broadcast_in_kernel: int = 0,
+        broadcast_materialized: int = 0,
     ) -> None:
         self.name = name
         self.source = source
@@ -113,10 +127,16 @@ class FusedKernel:
         self.kind = kind
         self.body_shape = body_shape
         self.out_shape = out_shape
-        #: minimum HBM traffic per launch (cluster inputs + root output,
-        #: from the inferred abstracts) — what the runtime profiler divides
-        #: wall time into for achieved-GB/s / roofline_fraction
+        #: HBM traffic per launch: each kernel operand at the size the
+        #: kernel reads it (a row / lane / scalar at its own size, a
+        #: materialized one at the body's) + the root output — what the
+        #: runtime profiler divides wall time into for achieved-GB/s /
+        #: roofline_fraction
         self.bytes_moved = bytes_moved
+        #: operands broadcast to the block in VMEM by the kernel itself
+        self.broadcast_in_kernel = broadcast_in_kernel
+        #: operands the wrapper still expands to the body shape in HBM
+        self.broadcast_materialized = broadcast_materialized
         self.oracle = oracle
         self.pallas_interpret = pallas_interpret
         self.pallas_compiled = pallas_compiled
@@ -200,11 +220,37 @@ def _abstract_nbytes(ab: Any) -> int:
     return 0
 
 
-def _cluster_bytes(cluster: Cluster) -> int:
-    """Minimum HBM traffic of one launch: every external input read once
-    plus the root output written once (interior values live in VMEM)."""
-    total = sum(_abstract_nbytes(n.abstract) for n in cluster.inputs)
-    return total + _abstract_nbytes(cluster.root.abstract)
+def _broadcast_input_shape(n: Apply, data_shape: tuple) -> tuple:
+    """The shape a broadcast member's data input has as the member
+    broadcasts it: ``unreduce`` with ``keepdims=False`` first re-inserts
+    the reduced axes as 1s (``broadcast_to`` takes it as it is)."""
+    shape = list(data_shape)
+    if n.fn.value.name == "unreduce":
+        shp, axes, keepdims = (a.value for a in n.args[1:])
+        if not keepdims:
+            for ax in _norm_axes(axes, len(shp)):
+                shape.insert(ax, 1)
+    return tuple(shape)
+
+
+def _small_block(shape: tuple, body_shape: tuple) -> tuple[int, int] | None:
+    """The 2-D form of an operand that broadcasts into the ``(R, C)``
+    kernel frame along whole rows or columns: ``(R, 1)`` (a row),
+    ``(1, C)`` (a lane) or ``(1, 1)`` (a scalar), read from its shape
+    left-padded to the body's rank.  None for an operand at the body
+    shape and for one that broadcasts over any other axes."""
+    nd = len(body_shape)
+    if len(shape) > nd:
+        return None
+    padded = (1,) * (nd - len(shape)) + tuple(int(d) for d in shape)
+    if padded == body_shape:
+        return None
+    lead_ones = all(d == 1 for d in padded[:-1])
+    if padded[-1] == 1 and (lead_ones or padded[:-1] == body_shape[:-1]):
+        return (1 if lead_ones else int(np.prod(body_shape[:-1])), 1)
+    if lead_ones and padded[-1] == body_shape[-1]:
+        return (1, body_shape[-1])
+    return None
 
 
 def emit_cluster(cluster: Cluster) -> FusedKernel | None:
@@ -293,9 +339,14 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
             return tuple(int(d) for d in np.shape(node.value))
         return None
 
+    # a broadcast member whose data input is a row / lane / scalar of the
+    # (R, C) frame runs in the kernel: that input is the operand and the
+    # kernel broadcasts it to the block in VMEM (member id -> (data ref,
+    # 2-D operand shape)); any other broadcast member runs in the wrapper
+    in_kernel: dict[int, tuple[str, tuple[int, int]]] = {}
     pre_name: dict[int, str] = {}
     pre_lines: list[str] = []
-    for k, n in enumerate(pre):
+    for n in pre:
         args = []
         for a in n.args:
             if a._id in members:
@@ -310,21 +361,40 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
                     f"unsupported external reference feeding {n.fn.value.name}",
                 )
             args.append(r)
+        data_shape = ext_shape(n.args[0])
+        if cluster.kind == "map" and data_shape is not None:
+            small = _small_block(_broadcast_input_shape(n, data_shape), body_shape)
+            if small is not None:
+                in_kernel[n._id] = (args[0], small)
+                continue
+        k = len(pre_name)
         pre_name[n._id] = f"p{k}"
         pre_lines.append(
             f"    p{k} = {bind_prim(n.fn.value)}({', '.join(args)})  # {n.fn.value.name} (pre)"
         )
 
-    # kernel operands: every value entering the elementwise body
-    operands: list[tuple[str, str, tuple | None]] = []  # (slot, wrapper expr, shape)
+    # the kernel's 2-D (R, C) frame
+    C = body_shape[-1]
+    R = int(np.prod(body_shape[:-1])) if len(body_shape) > 1 else 1
+
+    # kernel operands: every value entering the elementwise body, each of
+    # one kind — "full" (at the body shape), "small" (a row / lane /
+    # scalar the kernel broadcasts; its shape is the 2-D operand shape) or
+    # "wide" (expanded to the body shape in HBM by the wrapper):
+    # (slot, wrapper expr, shape, kind, itemsize)
+    operands: list[tuple[str, str, tuple, str, int]] = []
     operand_slot: dict[int, str] = {}
 
     def operand_for(a: Node) -> str | None:
         slot = operand_slot.get(a._id)
         if slot is not None:
             return slot
-        if a._id in pre_name:
+        if a._id in in_kernel:
+            r, shape = in_kernel[a._id]
+            expr, kind, dtype = f"jnp.reshape({r}, {shape})", "small", a.abstract.dtype
+        elif a._id in pre_name:
             expr, shape = pre_name[a._id], body_shape
+            kind, dtype = "wide", a.abstract.dtype
         else:
             r = ext_ref(a)
             if r is None or (
@@ -337,10 +407,20 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
             shape = ext_shape(a)
             if shape is None:
                 return None
-            expr = r
+            expr, kind = r, "full"
+            dtype = a.abstract.dtype if isinstance(a.abstract, AArray) else a.value.dtype
+            small = _small_block(shape, body_shape) if cluster.kind == "map" else None
+            if small is not None:
+                expr, shape, kind = f"jnp.reshape({r}, {small})", small, "small"
+            elif tuple(shape) != body_shape:
+                kind = "wide"
+        if kind == "small" and shape == (1, 1) and np.dtype(dtype).itemsize < 4:
+            # Mosaic cannot broadcast a packed (sub-32-bit) value across
+            # sublanes and lanes at once: the wrapper widens it to a lane
+            expr, shape = f"jnp.broadcast_to({expr}, (1, {C}))", (1, C)
         slot = f"x{len(operands)}"
         operand_slot[a._id] = slot
-        operands.append((slot, expr, shape))
+        operands.append((slot, expr, tuple(shape), kind, np.dtype(dtype).itemsize))
         return slot
 
     body_lines: list[str] = []
@@ -372,8 +452,6 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
     root_v = vname[cluster.root._id]
 
     # -- shapes & VMEM plan -------------------------------------------------
-    C = body_shape[-1]
-    R = int(np.prod(body_shape[:-1])) if len(body_shape) > 1 else 1
     out2 = (1, max(int(np.prod(out_shape)), 1)) if len(out_shape) < 2 else None
     sizes = [
         np.dtype(n.abstract.dtype).itemsize
@@ -381,10 +459,13 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
         if isinstance(n.abstract, AArray)
     ]
     widest, narrowest = max(sizes), min(sizes)
+    kinds = [op[3] for op in operands]
+    n_small = kinds.count("small")
     if cluster.kind == "map":
-        # every operand and the output double-buffered, plus one
-        # temporary per body node
-        elem_bytes = widest * (2 * (len(operands) + 1) + len(body))
+        # every operand at the body shape and the output double-buffered,
+        # plus one temporary per body node and per in-kernel broadcast
+        n_block = len(operands) - n_small
+        elem_bytes = widest * (2 * (n_block + 1) + len(body) + n_small)
         block = _map_block(R, C, _sublanes(narrowest), elem_bytes)
         if block is None:
             return DeclineReason(
@@ -402,6 +483,17 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
                 f"bytes of VMEM, over the {_VMEM_BUDGET}-byte budget",
             )
 
+    # HBM traffic: each operand as the kernel reads it, + the output.  A
+    # block is fetched again only when its block index changes between
+    # consecutive grid steps, so a lane operand is read once per row
+    # block when the columns are blocked; a row or scalar one, once
+    bytes_moved = _abstract_nbytes(cluster.root.abstract)
+    for _, _, shape, kind, itemsize in operands:
+        n = int(np.prod(shape if kind == "small" else body_shape))
+        if kind == "small" and shape[1] > 1 and bc < C:
+            n *= -(-R // br)
+        bytes_moved += n * itemsize
+
     _counter[0] += 1
     name = f"fused_{cluster.kind}{_counter[0]}_" + "_".join(
         dict.fromkeys(n.fn.value.name for n in cluster.order)
@@ -410,10 +502,13 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
 
     # -- source ------------------------------------------------------------
     nargs = ", ".join(f"a{i}" for i in range(len(cluster.inputs)))
-    krefs = ", ".join(f"{slot}_ref" for slot, _, _ in operands)
+    krefs = ", ".join(f"{op[0]}_ref" for op in operands)
     lines = [f"def _kernel({krefs}{', ' if krefs else ''}o_ref):"]
-    for slot, _, _ in operands:
-        lines.append(f"    {slot} = {slot}_ref[...]")
+    for slot, _, _, kind, _ in operands:
+        if kind == "small":
+            lines.append(f"    {slot} = jnp.broadcast_to({slot}_ref[...], ({br}, {bc}))")
+        else:
+            lines.append(f"    {slot} = {slot}_ref[...]")
     lines += [f"    {l}" for l in body_lines]
     if cluster.kind == "map":
         lines.append(f"    o_ref[...] = {root_v}")
@@ -423,27 +518,40 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
         lines.append(f"    o_ref[...] = jnp.reshape({root_v}, {out2 or out_shape})")
     lines.append("")
 
-    # wrapper: prepare operands at body shape, call pallas, restore shape
+    # wrapper: prepare operands (a small one in its 2-D shape, the rest at
+    # the body shape), call pallas, restore shape
     lines.append("def _make(interpret):")
     lines.append(f"    def {name}({nargs}):")
     for pl_line in pre_lines:
         lines.append("    " + pl_line)
-    call_args = []
-    for slot, expr, shape in operands:
+    if cluster.kind == "map":
+        spec = f"pl.BlockSpec(({br}, {bc}), lambda i, j: (i, j))"
+    call_args, in_specs = [], []
+    for slot, expr, shape, kind, _ in operands:
         e = expr
-        if shape != body_shape:
-            e = f"jnp.broadcast_to({e}, {body_shape})"
-        if cluster.kind == "map" and len(body_shape) != 2:
-            e = f"jnp.reshape({e}, ({R}, {C}))"
+        if kind == "small":
+            # its block spans its 1-long axes whole and follows the grid
+            # along the other
+            rows, cols = shape
+            in_specs.append(
+                f"pl.BlockSpec(({br if rows > 1 else 1}, {bc if cols > 1 else 1}), "
+                f"lambda i, j: ({'i' if rows > 1 else 0}, {'j' if cols > 1 else 0}))"
+            )
+        else:
+            if shape != body_shape:
+                e = f"jnp.broadcast_to({e}, {body_shape})"
+            if cluster.kind == "map":
+                in_specs.append(spec)
+                if len(body_shape) != 2:
+                    e = f"jnp.reshape({e}, ({R}, {C}))"
         lines.append(f"        {slot} = {e}")
         call_args.append(slot)
     if cluster.kind == "map":
-        spec = f"pl.BlockSpec(({br}, {bc}), lambda i, j: (i, j))"
         lines += [
             "        out = pl.pallas_call(",
             "            _kernel,",
             f"            grid=({-(-R // br)}, {-(-C // bc)}),",
-            "            in_specs=[" + ", ".join(spec for _ in operands) + "],",
+            "            in_specs=[" + ", ".join(in_specs) + "],",
             f"            out_specs={spec},",
             f"            out_shape=jax.ShapeDtypeStruct(({R}, {C}), _out_dtype),",
             "            interpret=interpret,",
@@ -505,5 +613,7 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
         oracle=oracle,
         pallas_interpret=interp,
         pallas_compiled=compiled,
-        bytes_moved=_cluster_bytes(cluster),
+        bytes_moved=bytes_moved,
+        broadcast_in_kernel=n_small,
+        broadcast_materialized=kinds.count("wide"),
     )

@@ -234,6 +234,8 @@ def _fusion_section(g: Any, options: Any) -> dict:
         if kernel is not None:
             entry["name"] = kernel.name
             entry["bytes_moved"] = kernel.bytes_moved
+            entry["broadcast_in_kernel"] = kernel.broadcast_in_kernel
+            entry["broadcast_materialized"] = kernel.broadcast_materialized
         if reason is not None:
             entry["reason"] = reason.as_dict()
         clusters.append(entry)

@@ -11,6 +11,8 @@
 * ``lowering_blockers`` de-duplicates; ``try_lower`` caches per graph.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,6 +27,7 @@ from repro.core.ir import toposort
 from repro.core.lowering import lower_graph, lowering_blockers, try_lower
 from repro.kernels import get_kernel_mode, set_kernel_mode
 from repro.kernels.codegen import emit_cluster
+from repro.obs.trace import Tracer, tracing
 
 
 @pytest.fixture(autouse=True)
@@ -67,6 +70,56 @@ def _masked_tanh(x, y):
 
 _F32 = jax.ShapeDtypeStruct((), jnp.float32)
 _F32DT = np.dtype("float32")
+
+
+# --- operands smaller than the body: broadcast in the kernel or the wrapper
+
+
+def _row_keepdims(x, m):
+    # the reduce_max adjoint's mask: a (B, S, 1) row max against the body
+    return P.cast(P.eq(x, P.unreduce(m, P.shape(x), (2,), True)), _F32DT)
+
+
+def _row_dropdims(x, m):
+    return P.cast(P.eq(x, P.unreduce(m, P.shape(x), (2,), False)), _F32DT)
+
+
+def _lane_vector(x, b):
+    return P.tanh(x) * P.broadcast_to(b, P.shape(x)) + x
+
+
+def _scalar_input(x, s):
+    return P.tanh(x) * s + x
+
+
+def _middle_axis(x, b):
+    return P.tanh(x) * P.broadcast_to(b, P.shape(x)) + x
+
+
+_BX = jnp.linspace(-2, 2, 4 * 16 * 1024).reshape(4, 16, 1024)  # (R, C) = (64, 1024)
+_BODY_BYTES = 64 * 1024 * 4
+
+#: (name, fn, example, in the kernel?, bytes_moved at the default budget,
+#: at the tiled one) — one full read + the output, plus the small operand
+#: as the kernel reads it: a lane is fetched again per row block (8 of
+#: them when tiled, 4 for bf16), a materialized operand reads at the
+#: body's size
+SMALL_OPERANDS = [
+    ("row_keepdims", _row_keepdims, (_BX, jnp.max(_BX, 2, keepdims=True)), True,
+     2 * _BODY_BYTES + 64 * 4, 2 * _BODY_BYTES + 64 * 4),
+    ("row_dropdims", _row_dropdims, (_BX, jnp.max(_BX, 2)), True,
+     2 * _BODY_BYTES + 64 * 4, 2 * _BODY_BYTES + 64 * 4),
+    ("lane_broadcast_to", _lane_vector, (_BX, jnp.linspace(0, 1, 1024)), True,
+     2 * _BODY_BYTES + 1024 * 4, 2 * _BODY_BYTES + 8 * 1024 * 4),
+    ("scalar", _scalar_input, (_BX, jnp.asarray(0.5, jnp.float32)), True,
+     2 * _BODY_BYTES + 4, 2 * _BODY_BYTES + 4),
+    # a packed scalar is widened to a (1, C) lane, which Mosaic can broadcast
+    ("scalar_bf16", _scalar_input,
+     (_BX.astype(jnp.bfloat16), jnp.asarray(0.5, jnp.bfloat16)), True,
+     _BODY_BYTES + 1024 * 2, _BODY_BYTES + 4 * 1024 * 2),
+    ("middle_axis", _middle_axis, (_BX, jnp.linspace(0, 1, 4096).reshape(4, 1, 1024)),
+     False, 3 * _BODY_BYTES, 3 * _BODY_BYTES),
+]
 
 CORPUS = [
     ("grad_cube", build_grad_graph, _cube, 0, (_F32,)),
@@ -270,6 +323,53 @@ class TestCodegen:
         assert br == R or (br % sub == 0 and br < R)
         assert bc == C or (bc % codegen._LANE == 0 and bc < C)
         assert br * bc * elem_bytes <= codegen._VMEM_BUDGET
+
+    @pytest.mark.parametrize("tiled", [False, True], ids=["whole", "tiled"])
+    @pytest.mark.parametrize(
+        "name,fn,example,in_kernel,nbytes,nbytes_tiled",
+        SMALL_OPERANDS,
+        ids=[c[0] for c in SMALL_OPERANDS],
+    )
+    def test_small_operand_broadcast(
+        self, monkeypatch, name, fn, example, in_kernel, nbytes, nbytes_tiled, tiled
+    ):
+        """A row / lane / scalar operand enters the map kernel at its own
+        size and is broadcast per block in VMEM; a middle-axis one is
+        still expanded by the wrapper.  Tiled (a 160 KiB budget blocks
+        both rows and columns, the lane case with a partial edge block)
+        exercises the small blocks' index maps.  Either way the kernel
+        is bit-identical to the oracle under jit."""
+        from repro.kernels import codegen
+
+        if tiled:
+            monkeypatch.setattr(codegen, "_VMEM_BUDGET", 160 * 1024)
+        g = _optimized(None, fn, 0, example)
+        (c,) = partition_graph(g).clusters
+        k = emit_cluster(c)
+        assert c.kind == "map" and k is not None
+        kernel_src, wrapper_src = k.source.split("def _oracle")[0].split("def _make")
+        grid = re.search(r"grid=\((\d+), (\d+)\)", wrapper_src).groups()
+        assert all(int(n) > 1 for n in grid) if tiled else grid == ("1", "1")
+        assert ("jnp.broadcast_to(x1_ref[...]" in kernel_src) is in_kernel
+        assert ("(pre)" in wrapper_src) is not in_kernel
+        assert (k.broadcast_in_kernel, k.broadcast_materialized) == (
+            (1, 0) if in_kernel else (0, 1)
+        )
+        assert k.bytes_moved == (nbytes_tiled if tiled else nbytes)
+
+        tracer = Tracer()
+        with tracing(tracer):
+            lower_graph(g, fuse=True)
+        (span,) = tracer.find("lower")
+        assert (span.attrs["broadcast_in_kernel"], span.attrs["broadcast_materialized"]) == (
+            k.broadcast_in_kernel, k.broadcast_materialized
+        )
+
+        args = [example[g.parameters.index(i)] for i in c.inputs]
+        got = np.asarray(jax.jit(k.pallas_interpret)(*args))
+        np.testing.assert_array_equal(got, np.asarray(jax.jit(k.oracle)(*args)))
+        if name.startswith("row"):
+            assert got.sum() == 64  # one max per row: the mask is not empty
 
     def test_scalar_graph_declines_but_lowers(self):
         """An all-opaque graph (scalar adjoint) produces no clusters and

@@ -8,14 +8,20 @@ worker that runs this file loads the TPU compiler, and every worker
 collects the same tests.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.core import P, parse_function
+from repro.core.api import compile_pipeline
 from repro.core.fusion import DeclineReason, partition_graph
-from repro.kernels.codegen import emit_cluster_explained
+from repro.core.infer import abstract_of_value
+from repro.kernels.codegen import emit_cluster, emit_cluster_explained
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rmsnorm import rmsnorm_bwd, rmsnorm_fwd
 from repro.kernels.ssd_scan import ssd_scan_fwd
@@ -78,6 +84,72 @@ def test_lm_train_step_kernels(lm_clusters, sds):
     for cluster, kernel in kernels:
         avals = [sds(n.abstract.shape, n.abstract.dtype) for n in cluster.inputs]
         assert _KERNEL in _compiled_text(kernel.pallas_compiled, *avals), kernel.name
+
+
+#: one HLO instruction: name, result dims, opcode, operand list
+_HLO_INSTR = re.compile(r"%([\w.-]+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(([^)]*)\)")
+
+
+def test_lm_max_adjoint_mask_broadcasts_in_vmem(lm_clusters, sds):
+    """The reduce_max adjoint's mask, ``cast(eq(logits, unreduce(m)))``,
+    takes its (B, S, 1) row max as a (B*S, 1) operand and broadcasts it
+    in the kernel: no broadcast at the logits' size is left in the HLO,
+    and the kernel reads exactly one body-size operand.  The tanh
+    adjoints have no small operand."""
+    by_name = {k.name: (c, k) for c, k, _ in lm_clusters if k is not None}
+    (name,) = [n for n in by_name if n.endswith("_unreduce_eq_cast")]
+    cluster, kernel = by_name[name]
+    body = math.prod(cluster.body_shape)
+    assert (kernel.broadcast_in_kernel, kernel.broadcast_materialized) == (1, 0)
+    # one read of the logits, one write of the mask, the row max once
+    assert kernel.bytes_moved == 2 * body * 4 + body // cluster.body_shape[-1] * 4
+    avals = [sds(n.abstract.shape, n.abstract.dtype) for n in cluster.inputs]
+    instrs = [
+        (n, math.prod(int(d) for d in dims.split(",") if d), op, args)
+        for n, dims, op, args in _HLO_INSTR.findall(
+            _compiled_text(kernel.pallas_compiled, *avals)
+        )
+    ]
+    elems = {n: size for n, size, _, _ in instrs}
+    assert not [n for n, size, op, _ in instrs if op == "broadcast" and size == body]
+    (call_args,) = [args for _, _, op, args in instrs if op == "custom-call"]
+    operands = re.findall(r"%([\w.-]+)", call_args)
+    assert [elems[o] == body for o in operands].count(True) == 1
+    mul_sub = [k for n, (_, k) in by_name.items() if n.endswith("_mul_sub")]
+    assert len(mul_sub) == 2 and all(k.broadcast_in_kernel == 0 for k in mul_sub)
+
+
+def _row_scale(x, m):
+    return P.tanh(x) * P.unreduce(m, P.shape(x), (2,), True) + x
+
+
+def _lane_scale(x, b):
+    return P.tanh(x) * P.broadcast_to(b, P.shape(x)) + x
+
+
+def _scalar_scale(x, s):
+    return P.tanh(x) * s + x
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize(
+    "fn,small_shape",
+    [(_row_scale, (16, 256, 1)), (_lane_scale, (92544,)), (_scalar_scale, ())],
+    ids=["row", "lane", "scalar"],
+)
+def test_small_operand_kernels(sds, fn, small_shape, dtype):
+    """Each small-operand kind broadcasts in VMEM and compiles at the
+    logits' widths, a packed (bf16) scalar included."""
+    body = (16, 256, 92544)
+    g = compile_pipeline(
+        parse_function(fn),
+        (abstract_of_value(sds(body, dtype)), abstract_of_value(sds(small_shape, dtype))),
+    )
+    (cluster,) = partition_graph(g).clusters
+    kernel = emit_cluster(cluster)
+    assert kernel.broadcast_in_kernel == 1
+    avals = [sds(n.abstract.shape, n.abstract.dtype) for n in cluster.inputs]
+    assert _KERNEL in _compiled_text(kernel.pallas_compiled, *avals)
 
 
 def test_lm_reduce_cluster_over_vmem_declines(lm_clusters):

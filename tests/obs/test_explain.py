@@ -135,6 +135,11 @@ def test_myia_function_explain_resolves_transforms():
     assert any(c["verdict"] == "emitted" for c in fus["clusters"])
     emitted = [c for c in fus["clusters"] if c["verdict"] == "emitted"]
     assert all(c["bytes_moved"] > 0 for c in emitted)
+    # the loss's scalar cotangent is unreduce-d into the first adjoint
+    # kernel: broadcast in VMEM, not expanded by the wrapper
+    assert [(c["broadcast_in_kernel"], c["broadcast_materialized"]) for c in emitted] == [
+        (1, 0), (0, 0)
+    ]
     fused = [n for n in fus["nodes"] if n["decision"] == "fused"]
     assert fused, "no node actually joined a cluster"
 
