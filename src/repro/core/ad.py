@@ -42,7 +42,14 @@ from .ir import (
 from .primitives import LOOP_NAMES, Primitive
 from .values import SymbolicKey, newenv
 
-__all__ = ["J", "Jprim", "build_grad_graph", "build_value_and_grad_graph", "build_vjp_graph"]
+__all__ = [
+    "J",
+    "Jprim",
+    "LoopAdjointStats",
+    "build_grad_graph",
+    "build_value_and_grad_graph",
+    "build_vjp_graph",
+]
 
 
 # ---------------------------------------------------------------------------
@@ -187,10 +194,39 @@ def _tuple_exit(name: str, n_params: int, sel: list[int]) -> Graph:
     return g
 
 
+class LoopAdjointStats:
+    """What the loop adjoints of one AD transform save, from static shapes:
+    ``loops``, the loop adjoints built (nested ones included, each once),
+    and ``saved_carry_bytes``, the bytes of their saved-carry stacks (a
+    ``scan_loop`` saves every carry of its trip count, a ``while_loop``
+    its checkpoint slots).  The ``ad.grad`` span carries both.  A loop
+    nested in another loop's step is transformed both with the enclosing
+    family and for the step's own VJP; it counts once."""
+
+    __slots__ = ("loops", "saved_carry_bytes", "_seen")
+
+    def __init__(self) -> None:
+        self.loops = 0
+        self.saved_carry_bytes = 0
+        self._seen: set[int] = set()
+
+    def record(self, loop: Node, slots: int, metas: list) -> None:
+        if loop._id in self._seen:
+            return
+        self._seen.add(loop._id)
+        self.loops += 1
+        self.saved_carry_bytes += slots * sum(
+            int(np.prod(shape, dtype=np.int64)) * np.dtype(dt).itemsize for shape, dt in metas
+        )
+
+
 class JTransformer:
-    def __init__(self, root: Graph, checkpoint_policy="auto") -> None:
+    def __init__(
+        self, root: Graph, checkpoint_policy="auto", stats: LoopAdjointStats | None = None
+    ) -> None:
         self.root = root
         self.checkpoint_slots = _policy_slots(checkpoint_policy)
+        self.stats = LoopAdjointStats() if stats is None else stats
         self.family = graph_and_descendants(root)
         self.graph_map: dict[Graph, Graph] = {}  # g -> ▶g
         self.bprop_graphs: dict[Graph, Graph] = {}  # g -> ◀g
@@ -390,8 +426,9 @@ class JTransformer:
             Constant(eg), *fins, *extras, debug_name=cur.debug_name
         )
 
-        vjp_sg = build_vjp_graph(sg)
-        vjp_eg = build_vjp_graph(eg)
+        self.stats.record(cur, L, metas)
+        vjp_sg = _vjp_graph(sg, "auto", self.stats)
+        vjp_eg = _vjp_graph(eg, "auto", self.stats)
 
         # backward: reversed scan over the saved-carry stacks; carry
         # (t, dc..., dacc_e...), extras (stk..., e...)
@@ -478,8 +515,9 @@ class JTransformer:
             Constant(eg), *fins, *extras, debug_name=cur.debug_name
         )
 
-        vjp_sg = build_vjp_graph(sg)
-        vjp_eg = build_vjp_graph(eg)
+        self.stats.record(cur, S, metas)
+        vjp_sg = _vjp_graph(sg, "auto", self.stats)
+        vjp_eg = _vjp_graph(eg, "auto", self.stats)
 
         b = Graph(f"◀{cur.debug_name or 'while_loop'}")
         b.flags["is_loop_bprop"] = True
@@ -721,12 +759,13 @@ class JTransformer:
         bg.set_return(bg.apply(P.make_tuple, env_node, *param_sens))
 
 
-def J(g: Graph, checkpoint_policy="auto") -> Graph:
-    """Transform ``g`` into ``▶g`` (cached on the graph)."""
+def J(g: Graph, checkpoint_policy="auto", stats: LoopAdjointStats | None = None) -> Graph:
+    """Transform ``g`` into ``▶g`` (cached on the graph); the loop
+    adjoints it builds are counted into ``stats``."""
     cached = g.transforms.get("J")
     if cached is not None:
         return cached
-    return JTransformer(g, checkpoint_policy).transform()
+    return JTransformer(g, checkpoint_policy, stats).transform()
 
 
 # ---------------------------------------------------------------------------
@@ -788,6 +827,20 @@ def _seed_cotangent(gg: Graph, out: Node) -> Node:
     return gg.apply(P.broadcast_to, one, gg.apply(P.shape, out))
 
 
+def _ad_transform(g: Graph, example_args, build) -> Graph:
+    """One AD transform of ``g`` inside the ``ad.grad`` span: the pre-grad
+    pipeline, then ``build(primal, stats)``; the span records the loop
+    adjoints that ``build`` counted into ``stats``."""
+    from repro.obs import trace as obs_trace
+
+    with obs_trace.span("ad.grad", graph=g.name) as sp:
+        g = _prepare_primal(g, example_args)
+        stats = LoopAdjointStats()
+        gg = build(g, stats)
+        sp.set(loops=stats.loops, saved_carry_bytes=stats.saved_carry_bytes)
+        return gg
+
+
 def build_grad_graph(
     g: Graph,
     wrt: int | tuple[int, ...] = 0,
@@ -801,28 +854,27 @@ def build_grad_graph(
     the pre-grad pipeline for loop-containing primals; ``checkpoint_policy``
     selects the while-loop adjoint's memory/recompute tradeoff (see
     ``repro.core.api.CompileOptions``)."""
-    from repro.obs import trace as obs_trace
+    return _ad_transform(
+        g, example_args,
+        lambda p, stats: _grad_graph(p, wrt, checkpoint_policy, stats, with_value=False),
+    )
 
-    with obs_trace.span("ad.grad", graph=g.name):
-        g = _prepare_primal(g, example_args)
-        return _build_grad_graph_body(g, wrt, checkpoint_policy)
 
-
-def _build_grad_graph_body(
-    g: Graph, wrt: int | tuple[int, ...], checkpoint_policy="auto"
+def _grad_graph(
+    g: Graph, wrt: int | tuple[int, ...], checkpoint_policy, stats, *, with_value: bool
 ) -> Graph:
-    jg = J(g, checkpoint_policy)
-    gg = Graph(f"grad_{g.name}")
+    jg = J(g, checkpoint_policy, stats)
+    gg = Graph(f"{'value_and_grad' if with_value else 'grad'}_{g.name}")
     params = [gg.add_parameter(p.debug_name) for p in g.parameters]
     japp = gg.apply(jg, *params)
     out = gg.apply(P.tuple_getitem, japp, 0)
     bp = gg.apply(P.tuple_getitem, japp, 1)
     grads = gg.apply(bp, _seed_cotangent(gg, out))
     if isinstance(wrt, int):
-        gg.set_return(gg.apply(P.tuple_getitem, grads, wrt + 1))
+        gnode = gg.apply(P.tuple_getitem, grads, wrt + 1)
     else:
-        items = [gg.apply(P.tuple_getitem, grads, i + 1) for i in wrt]
-        gg.set_return(gg.apply(P.make_tuple, *items))
+        gnode = gg.apply(P.make_tuple, *[gg.apply(P.tuple_getitem, grads, i + 1) for i in wrt])
+    gg.set_return(gg.apply(P.make_tuple, out, gnode) if with_value else gnode)
     gg.primal = g
     return gg
 
@@ -834,21 +886,10 @@ def build_value_and_grad_graph(
     example_args=None,
     checkpoint_policy="auto",
 ) -> Graph:
-    g = _prepare_primal(g, example_args)
-    jg = J(g, checkpoint_policy)
-    gg = Graph(f"value_and_grad_{g.name}")
-    params = [gg.add_parameter(p.debug_name) for p in g.parameters]
-    japp = gg.apply(jg, *params)
-    out = gg.apply(P.tuple_getitem, japp, 0)
-    bp = gg.apply(P.tuple_getitem, japp, 1)
-    grads = gg.apply(bp, _seed_cotangent(gg, out))
-    if isinstance(wrt, int):
-        gnode = gg.apply(P.tuple_getitem, grads, wrt + 1)
-    else:
-        gnode = gg.apply(P.make_tuple, *[gg.apply(P.tuple_getitem, grads, i + 1) for i in wrt])
-    gg.set_return(gg.apply(P.make_tuple, out, gnode))
-    gg.primal = g
-    return gg
+    return _ad_transform(
+        g, example_args,
+        lambda p, stats: _grad_graph(p, wrt, checkpoint_policy, stats, with_value=True),
+    )
 
 
 def build_vjp_graph(
@@ -856,8 +897,13 @@ def build_vjp_graph(
 ) -> Graph:
     """``vjp(f)``: graph ``(x1..xn, dout) -> (dx1..dxn)`` — arbitrary output
     cotangent (non-scalar outputs)."""
-    g = _prepare_primal(g, example_args)
-    jg = J(g, checkpoint_policy)
+    return _ad_transform(
+        g, example_args, lambda p, stats: _vjp_graph(p, checkpoint_policy, stats)
+    )
+
+
+def _vjp_graph(g: Graph, checkpoint_policy, stats: LoopAdjointStats) -> Graph:
+    jg = J(g, checkpoint_policy, stats)
     gg = Graph(f"vjp_{g.name}")
     params = [gg.add_parameter(p.debug_name) for p in g.parameters]
     dout = gg.add_parameter("dout")

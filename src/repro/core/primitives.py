@@ -222,6 +222,10 @@ def _impl_concat_grad(xs, axis, dout):
     return tuple(outs)
 
 
+def _impl_cumsum(x, axis, reverse):
+    return jax.lax.cumsum(x, axis=axis, reverse=bool(reverse))
+
+
 def _impl_cast(x, dtype):
     return jnp.asarray(x, dtype=dtype)
 
@@ -421,6 +425,7 @@ exp = register_primitive("exp", lambda x: jnp.exp(x))
 log = register_primitive("log", lambda x: jnp.log(x))
 tanh = register_primitive("tanh", lambda x: jnp.tanh(x))
 sigmoid = register_primitive("sigmoid", lambda x: jax.nn.sigmoid(x))
+softplus = register_primitive("softplus", lambda x: jax.nn.softplus(x))
 relu = register_primitive("relu", lambda x: jnp.maximum(x, 0))
 sqrt = register_primitive("sqrt", lambda x: jnp.sqrt(x))
 rsqrt = register_primitive(
@@ -479,6 +484,7 @@ slice_axis = register_primitive("slice_axis", _impl_slice_axis)
 pad_zeros_axis = register_primitive("pad_zeros_axis", _impl_pad_zeros_axis)
 concat_axis = register_primitive("concat_axis", _impl_concat_axis)
 concat_grad = register_primitive("concat_grad", _impl_concat_grad)
+cumsum = register_primitive("cumsum", _impl_cumsum)
 one_hot = register_primitive("one_hot", _impl_one_hot, bprop="zeros")
 
 # collectives: bprop=None — AD through a resharding point must fail loudly
@@ -561,6 +567,10 @@ def _bprop_tanh(x, out, dout):
 
 def _bprop_sigmoid(x, out, dout):
     return (mul(dout, mul(out, sub(1.0, out))),)
+
+
+def _bprop_softplus(x, out, dout):
+    return (mul(dout, sigmoid(x)),)
 
 
 def _bprop_relu(x, out, dout):
@@ -707,6 +717,10 @@ def _bprop_concat_grad(xs, axis, dout_in, out, dout):
     return (zeros_like(xs), zeros_like(axis), concat_axis(dout, axis))
 
 
+def _bprop_cumsum(x, axis, reverse, out, dout):
+    return (cumsum(dout, axis, bool_not(reverse)), zeros_like(axis), zeros_like(reverse))
+
+
 def _bprop_switch(c, t, f, out, dout):
     return (zeros_like(c), switch(c, dout, zeros_like(t)), switch(c, zeros_like(f), dout))
 
@@ -759,6 +773,7 @@ _BPROPS = {
     "log": _bprop_log,
     "tanh": _bprop_tanh,
     "sigmoid": _bprop_sigmoid,
+    "softplus": _bprop_softplus,
     "relu": _bprop_relu,
     "sqrt": _bprop_sqrt,
     "rsqrt": _bprop_rsqrt,
@@ -786,6 +801,7 @@ _BPROPS = {
     "pad_zeros_axis": _bprop_pad_zeros_axis,
     "concat_axis": _bprop_concat_axis,
     "concat_grad": _bprop_concat_grad,
+    "cumsum": _bprop_cumsum,
     "switch": _bprop_switch,
     "stop_gradient": _bprop_stop_gradient,
     "gadd": _bprop_gadd,

@@ -20,6 +20,8 @@ over the model axis.
 
 from __future__ import annotations
 
+from functools import partial
+
 import numpy as np
 
 import jax
@@ -36,6 +38,7 @@ __all__ = [
     "lm_in_specs",
     "init_lm_params",
     "make_myia_train_step",
+    "make_train_step",
 ]
 
 _take = P.take
@@ -125,23 +128,26 @@ def init_lm_params(dims: MyiaLMDims, rng: jax.Array) -> tuple:
     )
 
 
-def make_myia_train_step(
-    dims: MyiaLMDims, batch: int, seq: int, lr: float, *, fuse: bool = True
-):
-    """(step_fn, init_fn) for ``runtime.train_loop``.
+def make_train_step(loss, n_params: int, lr: float, init_params, *, fuse: bool = True,
+                    in_specs=None):
+    """(step_fn, init_fn) for ``runtime.train_loop``, for any loss written
+    in the Myia subset as ``loss(*params, tokens, labels)``.
 
     The loss+adjoint is one Myia graph (`value_and_grad` through the ST
-    transform); the SGD update is a handful of jax ops outside it.  The
-    MyiaFunction carries ``lm_in_specs`` — under an active mesh context
-    the step transparently switches to the sharded compilation tier.
-    """
+    transform, wrt the ``n_params`` leading arguments); the SGD update is
+    a handful of jax ops outside it.  ``init_params(rng)`` makes the
+    parameter tuple.  ``in_specs`` arms the sharded compilation tier under
+    an active mesh context."""
     vag = api.value_and_grad(
-        build_lm_loss(dims, batch, seq),
-        wrt=(0, 1, 2, 3),
-        options=api.CompileOptions(fuse=fuse, in_specs=lm_in_specs()),
+        loss,
+        wrt=tuple(range(n_params)),
+        options=api.CompileOptions(fuse=fuse, in_specs=in_specs),
     )
 
-    @jax.jit
+    # the gradients are dead after the update: donating them lets the new
+    # parameters take their buffers instead of a third set of weights
+    # being allocated while the loss+gradient's temporaries are live
+    @partial(jax.jit, donate_argnums=1)
     def _update(params, grads):
         gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in grads))
         new_params = tuple(p - lr * g for p, g in zip(params, grads))
@@ -159,7 +165,23 @@ def make_myia_train_step(
 
     def init_fn(rng=None):
         rng = jax.random.PRNGKey(0) if rng is None else rng
-        return {"params": init_lm_params(dims, rng), "step": jnp.zeros((), jnp.int32)}
+        return {"params": init_params(rng), "step": jnp.zeros((), jnp.int32)}
 
     step_fn.vag = vag  # introspection: tests/benchmarks reach the runner
     return step_fn, init_fn
+
+
+def make_myia_train_step(
+    dims: MyiaLMDims, batch: int, seq: int, lr: float, *, fuse: bool = True
+):
+    """(step_fn, init_fn) of the tanh LM (``make_train_step``); the
+    MyiaFunction carries ``lm_in_specs`` — under an active mesh context
+    the step transparently switches to the sharded compilation tier."""
+    return make_train_step(
+        build_lm_loss(dims, batch, seq),
+        4,
+        lr,
+        lambda rng: init_lm_params(dims, rng),
+        fuse=fuse,
+        in_specs=lm_in_specs(),
+    )

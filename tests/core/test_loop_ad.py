@@ -452,3 +452,22 @@ def test_loop_adjoint_aot_warm_restart_zero_compiles(tmp_path):
     assert warm["stats"]["xla_compiles"] == 0
     assert warm["stats"]["hits"] > 0
     assert warm["sum"] == cold["sum"]
+
+
+def test_tanh_lm_optimized_graph_is_unchanged():
+    """The tanh LM's loss+gradient graph, after the optimizer, hashes as it
+    did before the loop-adjoint accumulation rule and the shared step
+    builder: at a tiny size and at the benchmark's widths."""
+    from repro.launch.myia_step import MyiaLMDims, make_myia_train_step
+
+    want = {
+        512: "83ccd9e8228d6c90f687dee3e24b86bdcfe8b2f7d976a13cb22ac7d2354a750b",
+        92544: "c69f16bbe7c9e2bf086561d19a107f80999ee37714629fe3865ae1417811d06d",
+    }
+    for V, D, H, B, S in [(512, 64, 256, 4, 32), (92544, 2048, 8192, 16, 256)]:
+        vag = make_myia_train_step(MyiaLMDims(V, D, H), B, S, 0.1, fuse=True)[0].vag
+        f32, i32 = jnp.float32, jnp.int32
+        shapes = [((V, D), f32), ((D, H), f32), ((H, D), f32), ((D, V), f32),
+                  ((B, S), i32), ((B, S), i32)]
+        g = vag.optimized_graph(*[jax.ShapeDtypeStruct(s, d) for s, d in shapes])
+        assert structural_hash(g) == want[V]
