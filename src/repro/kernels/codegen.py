@@ -18,27 +18,30 @@ with three interchangeable execution paths, selected by the *same*
 Kernel shape strategy (blocks are legal for the TPU's Mosaic compiler
 and sized to a VMEM budget, :data:`_VMEM_BUDGET`):
 
-* **map clusters** (elementwise root): the body shape ``S`` is collapsed
-  to 2-D ``(R, C) = (prod(S[:-1]), S[-1])`` and the grid tiles it in
-  ``(br, bc)`` blocks, ``grid=(cdiv(R, br), cdiv(C, bc))``.  A block
-  dimension is either the whole array dimension or a multiple of the
-  tiling — ``br`` of the narrowest dtype's sublane count (8 rows of a
-  32-bit type, 16 of a 16-bit one, 32 of an 8-bit one), ``bc`` of the
-  128-lane width — so edge blocks may be partial, which elementwise
-  bodies tolerate.  Whole rows are kept when 8 of them fit the budget;
-  wider rows are blocked in columns too.  An operand smaller than ``S``
-  that broadcasts along whole rows or columns of the ``(R, C)`` frame —
-  a **row** ``(R, 1)``, a **lane** ``(1, C)`` or a **scalar** ``(1, 1)``,
-  read at the body's rank after ``unreduce``'s keepdims expansion and
-  numpy left-padding — enters the kernel at its own size, with a
-  ``(br, 1)``, ``(1, bc)`` or ``(1, 1)`` block, and the kernel
-  broadcasts it to ``(br, bc)`` in VMEM (a scalar narrower than 32 bits
-  enters as a ``(1, C)`` lane, which Mosaic can broadcast); a broadcast
-  member (``unreduce`` / ``broadcast_to``) of such an operand then runs
-  in the kernel.  Any other smaller operand (a broadcast over a middle
-  axis) is materialized *at* ``S`` by the wrapper (its broadcast member
-  runs there, or it is ``broadcast_to``-ed), so the body is per-block
-  elementwise code either way.
+* **map clusters** (elementwise root): every operand enters the kernel
+  at its own size — read at the body's rank after ``unreduce``'s keepdims
+  expansion and numpy left-padding, so each of its axes is the body's or
+  1 — and the kernel broadcasts it to the block in VMEM; a broadcast
+  member (``unreduce`` / ``broadcast_to``) runs in the kernel.  The
+  kernel's **frame** is the 2-D ``(R, C) = (prod(S[:-1]), S[-1])`` when
+  the operands are all full, rows ``(R, 1)``, lanes ``(1, C)`` or
+  scalars, and the body's own axes otherwise (see ``_frame``).  The
+  frame's last two axes are the **tile**, blocked
+  ``(br, bc)``: a block dimension is either the whole array dimension or
+  a multiple of the tiling — ``br`` of the narrowest dtype's sublane count
+  (8 rows of a 32-bit type, 16 of a 16-bit one, 32 of an 8-bit one),
+  ``bc`` of the 128-lane width — so edge blocks may be partial, which
+  elementwise bodies tolerate.  Whole rows are kept when 8 of them fit
+  the budget; wider rows are blocked in columns too.  When whole tiles
+  fit, the block takes as many as fit over the leading axes (innermost
+  first, an axis whole or by its largest divisor that fits).  An
+  operand's block has extent 1, at block index 0, on each axis it is 1
+  along.  The grid runs the leading axes along which the largest
+  in-kernel broadcast repeats fastest, so its block index holds between
+  consecutive steps and Pallas does not fetch it again.  A bool operand
+  enters as Pallas passes bools to Mosaic, int32 at its own size; a
+  value narrower than 32 bits that is 1 on both tile axes enters widened
+  to a lane, which Mosaic can broadcast.
 * **reduce clusters** (reduction root): one whole-array block (no grid)
   — the kernel computes the elementwise body and applies the reduction
   primitive with its static axes, so the floating-point reduction order
@@ -128,10 +131,10 @@ class FusedKernel:
         self.body_shape = body_shape
         self.out_shape = out_shape
         #: HBM traffic per launch: each kernel operand at the size the
-        #: kernel reads it (a row / lane / scalar at its own size, a
-        #: materialized one at the body's) + the root output — what the
-        #: runtime profiler divides wall time into for achieved-GB/s /
-        #: roofline_fraction
+        #: kernel reads it (a broadcast one at its own size times the
+        #: passes the grid makes over it, a materialized one at the
+        #: body's) + the root output — what the runtime profiler divides
+        #: wall time into for achieved-GB/s / roofline_fraction
         self.bytes_moved = bytes_moved
         #: operands broadcast to the block in VMEM by the kernel itself
         self.broadcast_in_kernel = broadcast_in_kernel
@@ -233,24 +236,38 @@ def _broadcast_input_shape(n: Apply, data_shape: tuple) -> tuple:
     return tuple(shape)
 
 
-def _small_block(shape: tuple, body_shape: tuple) -> tuple[int, int] | None:
-    """The 2-D form of an operand that broadcasts into the ``(R, C)``
-    kernel frame along whole rows or columns: ``(R, 1)`` (a row),
-    ``(1, C)`` (a lane) or ``(1, 1)`` (a scalar), read from its shape
-    left-padded to the body's rank.  None for an operand at the body
-    shape and for one that broadcasts over any other axes."""
-    nd = len(body_shape)
-    if len(shape) > nd:
-        return None
-    padded = (1,) * (nd - len(shape)) + tuple(int(d) for d in shape)
-    if padded == body_shape:
-        return None
-    lead_ones = all(d == 1 for d in padded[:-1])
-    if padded[-1] == 1 and (lead_ones or padded[:-1] == body_shape[:-1]):
-        return (1 if lead_ones else int(np.prod(body_shape[:-1])), 1)
-    if lead_ones and padded[-1] == body_shape[-1]:
-        return (1, body_shape[-1])
-    return None
+def _frame(body_shape: tuple, padded: list[tuple]) -> tuple[tuple, list[tuple]]:
+    """The map kernel's frame and each operand's shape in it: the 2-D
+    ``(R, C) = (prod(S[:-1]), S[-1])`` when every operand is whole or 1
+    along all the leading axes at once (full, a row, a lane or a scalar),
+    else the body's own axes: a reshape that merged leading axes there
+    would let XLA lay the operand's producer out otherwise than the
+    kernel's row-major operand and add a relayout copy between them (it
+    did for the attention's scores on a v5e)."""
+    lead = body_shape[:-1]
+    if all(p[:-1] == lead or set(p[:-1]) <= {1} for p in padded):
+        return (math.prod(lead), body_shape[-1]), [(math.prod(p[:-1]), p[-1]) for p in padded]
+    return body_shape, padded
+
+
+def _lead_block(dims: tuple, room: int) -> tuple:
+    """Block extents over a frame's leading axes when ``room`` whole tiles
+    fit the budget: from the innermost axis outwards each axis is taken
+    whole while it fits, then the largest divisor of the next that fits,
+    and every axis further out at 1."""
+    out = []
+    for d in reversed(dims):
+        b = next(k for k in range(max(1, min(d, room)), 0, -1) if d % k == 0)
+        out.append(b)
+        room = room // d if b == d else 1
+    return tuple(reversed(out))
+
+
+def _memref_itemsize(dtype) -> int:
+    """Bytes an element of ``dtype`` takes as a kernel operand: Mosaic
+    holds a bool memref as int32 (Pallas converts it at the boundary)."""
+    dtype = np.dtype(dtype)
+    return 4 if dtype == np.bool_ else dtype.itemsize
 
 
 def emit_cluster(cluster: Cluster) -> FusedKernel | None:
@@ -339,11 +356,11 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
             return tuple(int(d) for d in np.shape(node.value))
         return None
 
-    # a broadcast member whose data input is a row / lane / scalar of the
-    # (R, C) frame runs in the kernel: that input is the operand and the
-    # kernel broadcasts it to the block in VMEM (member id -> (data ref,
-    # 2-D operand shape)); any other broadcast member runs in the wrapper
-    in_kernel: dict[int, tuple[str, tuple[int, int]]] = {}
+    # a map cluster's broadcast member runs in the kernel: its data input is
+    # the operand, and the kernel broadcasts it to the block in VMEM (member
+    # id -> (data ref, the shape the member broadcasts)); a reduce
+    # cluster's runs in the wrapper
+    in_kernel: dict[int, tuple[str, tuple]] = {}
     pre_name: dict[int, str] = {}
     pre_lines: list[str] = []
     for n in pre:
@@ -361,40 +378,32 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
                     f"unsupported external reference feeding {n.fn.value.name}",
                 )
             args.append(r)
-        data_shape = ext_shape(n.args[0])
-        if cluster.kind == "map" and data_shape is not None:
-            small = _small_block(_broadcast_input_shape(n, data_shape), body_shape)
-            if small is not None:
-                in_kernel[n._id] = (args[0], small)
-                continue
+        if cluster.kind == "map":
+            in_kernel[n._id] = (args[0], _broadcast_input_shape(n, ext_shape(n.args[0])))
+            continue
         k = len(pre_name)
         pre_name[n._id] = f"p{k}"
         pre_lines.append(
             f"    p{k} = {bind_prim(n.fn.value)}({', '.join(args)})  # {n.fn.value.name} (pre)"
         )
 
-    # the kernel's 2-D (R, C) frame
-    C = body_shape[-1]
-    R = int(np.prod(body_shape[:-1])) if len(body_shape) > 1 else 1
-
-    # kernel operands: every value entering the elementwise body, each of
-    # one kind — "full" (at the body shape), "small" (a row / lane /
-    # scalar the kernel broadcasts; its shape is the 2-D operand shape) or
-    # "wide" (expanded to the body shape in HBM by the wrapper):
-    # (slot, wrapper expr, shape, kind, itemsize)
-    operands: list[tuple[str, str, tuple, str, int]] = []
+    # kernel operands: every value entering the elementwise body, as
+    # (slot, wrapper ref, own shape, dtype, whether the wrapper computes
+    # it at the body shape: a reduce cluster's broadcast member)
+    found: list[tuple[str, str, tuple, Any, bool]] = []
     operand_slot: dict[int, str] = {}
 
     def operand_for(a: Node) -> str | None:
         slot = operand_slot.get(a._id)
         if slot is not None:
             return slot
+        wide = False
         if a._id in in_kernel:
             r, shape = in_kernel[a._id]
-            expr, kind, dtype = f"jnp.reshape({r}, {shape})", "small", a.abstract.dtype
+            dtype = a.abstract.dtype
         elif a._id in pre_name:
-            expr, shape = pre_name[a._id], body_shape
-            kind, dtype = "wide", a.abstract.dtype
+            r, shape, dtype = pre_name[a._id], body_shape, a.abstract.dtype
+            wide = True
         else:
             r = ext_ref(a)
             if r is None or (
@@ -407,20 +416,10 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
             shape = ext_shape(a)
             if shape is None:
                 return None
-            expr, kind = r, "full"
             dtype = a.abstract.dtype if isinstance(a.abstract, AArray) else a.value.dtype
-            small = _small_block(shape, body_shape) if cluster.kind == "map" else None
-            if small is not None:
-                expr, shape, kind = f"jnp.reshape({r}, {small})", small, "small"
-            elif tuple(shape) != body_shape:
-                kind = "wide"
-        if kind == "small" and shape == (1, 1) and np.dtype(dtype).itemsize < 4:
-            # Mosaic cannot broadcast a packed (sub-32-bit) value across
-            # sublanes and lanes at once: the wrapper widens it to a lane
-            expr, shape = f"jnp.broadcast_to({expr}, (1, {C}))", (1, C)
-        slot = f"x{len(operands)}"
+        slot = f"x{len(found)}"
         operand_slot[a._id] = slot
-        operands.append((slot, expr, tuple(shape), kind, np.dtype(dtype).itemsize))
+        found.append((slot, r, tuple(int(d) for d in shape), dtype, wide))
         return slot
 
     body_lines: list[str] = []
@@ -459,21 +458,74 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
         if isinstance(n.abstract, AArray)
     ]
     widest, narrowest = max(sizes), min(sizes)
+    # each operand as (slot, wrapper expr, shape it enters with, kind,
+    # itemsize), of one kind: "full" (at the kernel's frame), "small" (at
+    # its own size, broadcast by the kernel per block in VMEM) or "wide"
+    # (a reduce cluster's, expanded to the body shape in HBM by the wrapper)
+    operands: list[tuple[str, str, tuple, str, int]] = []
+    if cluster.kind == "map":
+        # each operand's shape left-padded to the body's rank (numpy's
+        # broadcasting): every axis is then the body's or 1
+        nd = len(body_shape)
+        padded = [(1,) * (nd - len(shape)) + shape for _, _, shape, _, _ in found]
+        frame, shapes = _frame(body_shape, padded)
+        for (slot, r, own, dtype, _), shape in zip(found, shapes):
+            if shape == frame:
+                expr, kind = (r if own == frame else f"jnp.reshape({r}, {frame})"), "full"
+            else:
+                expr, kind = f"jnp.reshape({r}, {shape})", "small"
+                if shape[-2:] == (1, 1) and np.dtype(dtype).itemsize < 4:
+                    # Mosaic cannot broadcast a packed (sub-32-bit) value
+                    # across sublanes and lanes at once: the wrapper widens
+                    # it to a lane
+                    shape = shape[:-1] + (frame[-1],)
+                    expr = f"jnp.broadcast_to({expr}, {shape})"
+            operands.append((slot, expr, shape, kind, _memref_itemsize(dtype)))
+    else:
+        for slot, r, own, dtype, wide in found:
+            if wide or own != body_shape:
+                expr = r if own == body_shape else f"jnp.broadcast_to({r}, {body_shape})"
+                operands.append((slot, expr, body_shape, "wide", _memref_itemsize(dtype)))
+            else:
+                operands.append((slot, r, body_shape, "full", _memref_itemsize(dtype)))
     kinds = [op[3] for op in operands]
     n_small = kinds.count("small")
     if cluster.kind == "map":
-        # every operand at the body shape and the output double-buffered,
-        # plus one temporary per body node and per in-kernel broadcast
-        n_block = len(operands) - n_small
-        elem_bytes = widest * (2 * (n_block + 1) + len(body) + n_small)
-        block = _map_block(R, C, _sublanes(narrowest), elem_bytes)
-        if block is None:
+        # operands spanning the whole tile (the frame's last two axes) and
+        # the output double-buffered, plus one temporary per body node and
+        # per in-kernel broadcast
+        tile = frame[-2:]
+        n_tile = sum(op[2][-2:] == tile for op in operands)
+        elem_bytes = widest * (2 * (n_tile + 1) + len(body) + n_small)
+        sub = _sublanes(narrowest)
+        got = _map_block(*tile, sub, elem_bytes)
+        if got is None:
             return DeclineReason(
                 DeclineReason.VMEM,
-                f"no ({_sublanes(narrowest)}, {_LANE})-aligned block of the "
-                f"{R}x{C} body fits {_VMEM_BUDGET} bytes of VMEM",
+                f"no ({sub}, {_LANE})-aligned block of the "
+                f"{tile[0]}x{tile[1]} tile fits {_VMEM_BUDGET} bytes of VMEM",
             )
-        br, bc = block
+        room = 1
+        if got == tile:
+            # whole tiles (each at its sublane- and lane-padded size): the
+            # block takes as many as fit over the leading axes
+            padded_tile = -(-got[0] // sub) * sub * (-(-got[1] // _LANE) * _LANE)
+            room = _VMEM_BUDGET // elem_bytes // padded_tile
+        block = (*_lead_block(frame[:-2], room), *got)
+        grid = [-(-f // b) for f, b in zip(frame, block)]
+        lead = range(len(frame) - 2)
+        names = [f"g{a}" for a in lead] + ["i", "j"]
+        # the leading axes along which the largest in-kernel broadcast
+        # repeats vary fastest: its block index then holds between
+        # consecutive grid steps, and a held block is not fetched again
+        big = max(
+            (op for op in operands if op[3] == "small"),
+            key=lambda op: math.prod(op[2]) * op[4],
+            default=None,
+        )
+        rep = [a for a in lead if big is not None and big[2][a] == 1 and grid[a] > 1]
+        order = [a for a in lead if a not in rep] + [len(frame) - 2, len(frame) - 1] + rep
+        params = ", ".join(names[a] for a in order)
     else:
         need = widest * int(np.prod(body_shape)) * (len(operands) + len(body))
         if need > _VMEM_BUDGET:
@@ -485,13 +537,17 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
 
     # HBM traffic: each operand as the kernel reads it, + the output.  A
     # block is fetched again only when its block index changes between
-    # consecutive grid steps, so a lane operand is read once per row
-    # block when the columns are blocked; a row or scalar one, once
+    # consecutive grid steps, so an operand is read whole once, and once
+    # more for each step of a grid axis it does not span (it is 1 there)
+    # outside the innermost axis it does span
     bytes_moved = _abstract_nbytes(cluster.root.abstract)
-    for _, _, shape, kind, itemsize in operands:
-        n = int(np.prod(shape if kind == "small" else body_shape))
-        if kind == "small" and shape[1] > 1 and bc < C:
-            n *= -(-R // br)
+    for _, _, shape, _, itemsize in operands:
+        n = int(np.prod(shape))
+        if cluster.kind == "map":
+            spans = [shape[a] > 1 and grid[a] > 1 for a in order]
+            if any(spans):
+                inner = len(spans) - 1 - spans[::-1].index(True)
+                n *= math.prod(grid[a] for a, s in zip(order[:inner], spans) if not s)
         bytes_moved += n * itemsize
 
     _counter[0] += 1
@@ -506,7 +562,7 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
     lines = [f"def _kernel({krefs}{', ' if krefs else ''}o_ref):"]
     for slot, _, _, kind, _ in operands:
         if kind == "small":
-            lines.append(f"    {slot} = jnp.broadcast_to({slot}_ref[...], ({br}, {bc}))")
+            lines.append(f"    {slot} = jnp.broadcast_to({slot}_ref[...], {block})")
         else:
             lines.append(f"    {slot} = {slot}_ref[...]")
     lines += [f"    {l}" for l in body_lines]
@@ -518,42 +574,34 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
         lines.append(f"    o_ref[...] = jnp.reshape({root_v}, {out2 or out_shape})")
     lines.append("")
 
-    # wrapper: prepare operands (a small one in its 2-D shape, the rest at
-    # the body shape), call pallas, restore shape
+    # wrapper: prepare operands (each in its frame shape, or a reduce
+    # cluster's at the body shape), call pallas, restore shape
     lines.append("def _make(interpret):")
     lines.append(f"    def {name}({nargs}):")
     for pl_line in pre_lines:
         lines.append("    " + pl_line)
     if cluster.kind == "map":
-        spec = f"pl.BlockSpec(({br}, {bc}), lambda i, j: (i, j))"
+        spec = f"pl.BlockSpec({block}, lambda {params}: ({', '.join(names)}))"
     call_args, in_specs = [], []
     for slot, expr, shape, kind, _ in operands:
-        e = expr
         if kind == "small":
-            # its block spans its 1-long axes whole and follows the grid
-            # along the other
-            rows, cols = shape
-            in_specs.append(
-                f"pl.BlockSpec(({br if rows > 1 else 1}, {bc if cols > 1 else 1}), "
-                f"lambda i, j: ({'i' if rows > 1 else 0}, {'j' if cols > 1 else 0}))"
-            )
-        else:
-            if shape != body_shape:
-                e = f"jnp.broadcast_to({e}, {body_shape})"
-            if cluster.kind == "map":
-                in_specs.append(spec)
-                if len(body_shape) != 2:
-                    e = f"jnp.reshape({e}, ({R}, {C}))"
-        lines.append(f"        {slot} = {e}")
+            # its block spans its 1-long axes whole (block index 0) and
+            # follows the grid along the others
+            ext = tuple(b if d > 1 else 1 for d, b in zip(shape, block))
+            idx = ", ".join(n if d > 1 else "0" for d, n in zip(shape, names))
+            in_specs.append(f"pl.BlockSpec({ext}, lambda {params}: ({idx}))")
+        elif cluster.kind == "map":
+            in_specs.append(spec)
+        lines.append(f"        {slot} = {expr}")
         call_args.append(slot)
     if cluster.kind == "map":
         lines += [
             "        out = pl.pallas_call(",
             "            _kernel,",
-            f"            grid=({-(-R // br)}, {-(-C // bc)}),",
+            f"            grid={tuple(grid[a] for a in order)},",
             "            in_specs=[" + ", ".join(in_specs) + "],",
             f"            out_specs={spec},",
-            f"            out_shape=jax.ShapeDtypeStruct(({R}, {C}), _out_dtype),",
+            f"            out_shape=jax.ShapeDtypeStruct({frame}, _out_dtype),",
             "            interpret=interpret,",
             f"            name={name!r},",
             f"        )({', '.join(call_args)})",

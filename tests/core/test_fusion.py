@@ -11,6 +11,7 @@
 * ``lowering_blockers`` de-duplicates; ``try_lower`` caches per graph.
 """
 
+import os
 import re
 
 import jax
@@ -72,7 +73,7 @@ _F32 = jax.ShapeDtypeStruct((), jnp.float32)
 _F32DT = np.dtype("float32")
 
 
-# --- operands smaller than the body: broadcast in the kernel or the wrapper
+# --- operands smaller than the body: broadcast per block in the kernel
 
 
 def _row_keepdims(x, m):
@@ -96,29 +97,52 @@ def _middle_axis(x, b):
     return P.tanh(x) * P.broadcast_to(b, P.shape(x)) + x
 
 
+def _mask_tile(x, m):
+    # the SSD's causal mask: a (Q, Q) bool tile under every leading axis
+    return P.where(m, P.tanh(x) * 2.0, x)
+
+
 _BX = jnp.linspace(-2, 2, 4 * 16 * 1024).reshape(4, 16, 1024)  # (R, C) = (64, 1024)
 _BODY_BYTES = 64 * 1024 * 4
+#: a small SSD-shaped body: (batch, heads, chunks, rows, columns)
+_X5 = jnp.linspace(-2, 2, 2 * 2 * 2 * 16 * 1024).reshape(2, 2, 2, 16, 1024)
+_BODY5_BYTES = 2 * 2 * 2 * 16 * 1024 * 4
+_MASK = jnp.arange(16).reshape(16, 1) >= jnp.arange(1024).reshape(1, 1024)
 
-#: (name, fn, example, in the kernel?, bytes_moved at the default budget,
-#: at the tiled one) — one full read + the output, plus the small operand
-#: as the kernel reads it: a lane is fetched again per row block (8 of
-#: them when tiled, 4 for bf16), a materialized operand reads at the
-#: body's size
+#: (name, fn, example, bytes_moved at the default budget, at the tiled
+#: one) — one full read + the output, plus the operand broadcast in the
+#: kernel as the kernel reads it: at its own size, once more for each step
+#: of a grid axis it is 1 along that lies outside the innermost one it
+#: spans.  A lane is fetched again per row block (8 of them when tiled, 4
+#: for bf16), a (B, 1, ..) middle-axis operand once per row block too (2
+#: when tiled); a tile under leading axes, or a head-axis broadcast, is
+#: read once, since the axes it repeats along vary fastest in the grid; a
+#: bool enters as int32
 SMALL_OPERANDS = [
-    ("row_keepdims", _row_keepdims, (_BX, jnp.max(_BX, 2, keepdims=True)), True,
+    ("row_keepdims", _row_keepdims, (_BX, jnp.max(_BX, 2, keepdims=True)),
      2 * _BODY_BYTES + 64 * 4, 2 * _BODY_BYTES + 64 * 4),
-    ("row_dropdims", _row_dropdims, (_BX, jnp.max(_BX, 2)), True,
+    ("row_dropdims", _row_dropdims, (_BX, jnp.max(_BX, 2)),
      2 * _BODY_BYTES + 64 * 4, 2 * _BODY_BYTES + 64 * 4),
-    ("lane_broadcast_to", _lane_vector, (_BX, jnp.linspace(0, 1, 1024)), True,
+    ("lane_broadcast_to", _lane_vector, (_BX, jnp.linspace(0, 1, 1024)),
      2 * _BODY_BYTES + 1024 * 4, 2 * _BODY_BYTES + 8 * 1024 * 4),
-    ("scalar", _scalar_input, (_BX, jnp.asarray(0.5, jnp.float32)), True,
+    ("scalar", _scalar_input, (_BX, jnp.asarray(0.5, jnp.float32)),
      2 * _BODY_BYTES + 4, 2 * _BODY_BYTES + 4),
     # a packed scalar is widened to a (1, C) lane, which Mosaic can broadcast
     ("scalar_bf16", _scalar_input,
-     (_BX.astype(jnp.bfloat16), jnp.asarray(0.5, jnp.bfloat16)), True,
+     (_BX.astype(jnp.bfloat16), jnp.asarray(0.5, jnp.bfloat16)),
      _BODY_BYTES + 1024 * 2, _BODY_BYTES + 4 * 1024 * 2),
     ("middle_axis", _middle_axis, (_BX, jnp.linspace(0, 1, 4096).reshape(4, 1, 1024)),
-     False, 3 * _BODY_BYTES, 3 * _BODY_BYTES),
+     2 * _BODY_BYTES + 4096 * 4, 2 * _BODY_BYTES + 2 * 4096 * 4),
+    ("tile_leading", _middle_axis, (_X5, jnp.linspace(0, 1, 16 * 1024).reshape(16, 1024)),
+     2 * _BODY5_BYTES + 16 * 1024 * 4, 2 * _BODY5_BYTES + 16 * 1024 * 4),
+    ("head_axis", _scalar_input,
+     (_X5, jnp.linspace(0, 1, 2 * 2 * 16 * 1024).reshape(2, 1, 2, 16, 1024)),
+     2 * _BODY5_BYTES + 2 * 2 * 16 * 1024 * 4, 2 * _BODY5_BYTES + 2 * 2 * 16 * 1024 * 4),
+    ("second_to_last", _scalar_input,
+     (_X5, jnp.linspace(0, 1, 2 * 2 * 2 * 1024).reshape(2, 2, 2, 1, 1024)),
+     2 * _BODY5_BYTES + 8 * 1024 * 4, 2 * _BODY5_BYTES + 2 * 8 * 1024 * 4),
+    ("bool_mask_tile", _mask_tile, (_X5, _MASK),
+     2 * _BODY5_BYTES + 16 * 1024 * 4, 2 * _BODY5_BYTES + 16 * 1024 * 4),
 ]
 
 CORPUS = [
@@ -326,19 +350,21 @@ class TestCodegen:
 
     @pytest.mark.parametrize("tiled", [False, True], ids=["whole", "tiled"])
     @pytest.mark.parametrize(
-        "name,fn,example,in_kernel,nbytes,nbytes_tiled",
+        "name,fn,example,nbytes,nbytes_tiled",
         SMALL_OPERANDS,
         ids=[c[0] for c in SMALL_OPERANDS],
     )
     def test_small_operand_broadcast(
-        self, monkeypatch, name, fn, example, in_kernel, nbytes, nbytes_tiled, tiled
+        self, monkeypatch, name, fn, example, nbytes, nbytes_tiled, tiled
     ):
-        """A row / lane / scalar operand enters the map kernel at its own
-        size and is broadcast per block in VMEM; a middle-axis one is
-        still expanded by the wrapper.  Tiled (a 160 KiB budget blocks
-        both rows and columns, the lane case with a partial edge block)
-        exercises the small blocks' index maps.  Either way the kernel
-        is bit-identical to the oracle under jit."""
+        """An operand that broadcasts into the body along any axes — a row,
+        a lane, a scalar, a middle axis, a tile under leading axes, one
+        head axis, a bool mask — enters the map kernel at its own size and
+        is broadcast per block in VMEM; the wrapper expands nothing to the
+        body shape.  Tiled (a 160 KiB budget blocks every axis, the lane
+        and middle-axis cases with a partial edge block) exercises the
+        blocks' index maps and the grid order.  Either way the kernel is
+        bit-identical to the oracle under jit."""
         from repro.kernels import codegen
 
         if tiled:
@@ -348,13 +374,12 @@ class TestCodegen:
         k = emit_cluster(c)
         assert c.kind == "map" and k is not None
         kernel_src, wrapper_src = k.source.split("def _oracle")[0].split("def _make")
-        grid = re.search(r"grid=\((\d+), (\d+)\)", wrapper_src).groups()
-        assert all(int(n) > 1 for n in grid) if tiled else grid == ("1", "1")
-        assert ("jnp.broadcast_to(x1_ref[...]" in kernel_src) is in_kernel
-        assert ("(pre)" in wrapper_src) is not in_kernel
-        assert (k.broadcast_in_kernel, k.broadcast_materialized) == (
-            (1, 0) if in_kernel else (0, 1)
-        )
+        grid = [int(n) for n in re.search(r"grid=\(([\d, ]+)\)", wrapper_src)[1].split(",")]
+        assert all(n > 1 for n in grid) if tiled else all(n == 1 for n in grid)
+        assert "jnp.broadcast_to(x1_ref[...]" in kernel_src
+        assert "(pre)" not in wrapper_src
+        assert not re.search(rf"broadcast_to\(.*, {re.escape(str(c.body_shape))}\)", wrapper_src)
+        assert (k.broadcast_in_kernel, k.broadcast_materialized) == (1, 0)
         assert k.bytes_moved == (nbytes_tiled if tiled else nbytes)
 
         tracer = Tracer()
@@ -370,6 +395,27 @@ class TestCodegen:
         np.testing.assert_array_equal(got, np.asarray(jax.jit(k.oracle)(*args)))
         if name.startswith("row"):
             assert got.sum() == 64  # one max per row: the mask is not empty
+
+    @pytest.mark.parametrize("batch", [16, 32])
+    def test_tanh_lm_kernels_keep_their_source(self, batch):
+        """The tanh LM's three kernels at the benchmark's widths
+        (``unreduce_eq_cast`` and the two ``mul_sub``), whose operands are
+        all full, rows, lanes or scalars, are generated byte for byte as
+        in ``tests/core/golden`` (the numbered kernel name masked, since
+        it depends on what the process emitted before)."""
+        from repro.launch.myia_step import MyiaLMDims, make_myia_train_step
+
+        V, D, H, S = 92544, 2048, 8192, 256
+        vag = make_myia_train_step(MyiaLMDims(V, D, H), batch, S, 3e-4, fuse=True)[0].vag
+        args = [jax.ShapeDtypeStruct(s, jnp.float32) for s in ((V, D), (D, H), (H, D), (D, V))]
+        args += [jax.ShapeDtypeStruct((batch, S), jnp.int32)] * 2
+        kernels = [emit_cluster(c) for c in partition_graph(vag.optimized_graph(*args)).clusters]
+        got = "".join(
+            re.sub(r"fused_map\d+_", "fused_mapN_", k.source) for k in kernels if k is not None
+        )
+        golden = os.path.join(os.path.dirname(__file__), "golden", f"tanh_lm_b{batch}_s{S}.txt")
+        with open(golden) as f:
+            assert got == f.read()
 
     def test_scalar_graph_declines_but_lowers(self):
         """An all-opaque graph (scalar adjoint) produces no clusters and

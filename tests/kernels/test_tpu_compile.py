@@ -8,7 +8,9 @@ worker that runs this file loads the TPU compiler, and every worker
 collects the same tests.
 """
 
+import json
 import math
+import os
 import re
 
 import jax
@@ -24,10 +26,14 @@ from repro.core.infer import abstract_of_value
 from repro.kernels.codegen import emit_cluster, emit_cluster_explained
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rmsnorm import rmsnorm_bwd, rmsnorm_fwd
+from repro.core.ir import Constant, Graph, toposort
+from repro.core.primitives import LOOP_GRAPH_ARGS
 from repro.kernels.ssd_scan import ssd_scan_fwd
+from repro.launch.myia_hybrid import HybridDims, init_hybrid_params, make_hybrid_train_step
 from repro.launch.myia_step import MyiaLMDims, make_myia_train_step
 
 _KERNEL = 'custom_call_target="tpu_custom_call"'
+_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 @pytest.fixture(scope="module")
@@ -150,6 +156,60 @@ def test_small_operand_kernels(sds, fn, small_shape, dtype):
     assert kernel.broadcast_in_kernel == 1
     avals = [sds(n.abstract.shape, n.abstract.dtype) for n in cluster.inputs]
     assert _KERNEL in _compiled_text(kernel.pallas_compiled, *avals)
+
+
+def _clusters_with_loop_bodies(g):
+    """The fusion clusters of ``g`` and of every loop body in it, as the
+    lowering partitions each of them."""
+    out = partition_graph(g).clusters
+    for n in toposort(g):
+        if n.is_apply and isinstance(n.fn, Constant):
+            for sub in n.args[: LOOP_GRAPH_ARGS.get(getattr(n.fn.value, "name", None), 0)]:
+                if isinstance(sub, Constant) and isinstance(sub.value, Graph):
+                    out += _clusters_with_loop_bodies(sub.value)
+    return out
+
+
+@pytest.fixture(scope="module")
+def hybrid_ssd_kernels():
+    """The distinct generated kernels at the SSD's (B, H, NC, Q, Q) body
+    in the Granite 4.0-H Micro loss+gradient at published widths, B 2 x S
+    2048 (the benchmark cell's): the forward's masked decays times C B^T,
+    the recompute's decays and the adjoint's product, by kernel name with
+    its number dropped."""
+    with open(os.path.join(_ROOT, "bench", "configs", "myia-granite4h-micro.json")) as f:
+        dims = HybridDims(json.load(f))
+    B, S = 2, 2048
+    vag = make_hybrid_train_step(dims, B, S, 3e-4, fuse=True)[0].vag
+    params = jax.eval_shape(lambda: init_hybrid_params(dims, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((B, S), jnp.int32)
+    g = vag.optimized_graph(*params, tokens, tokens)
+    body = (B, dims.heads, S // dims.chunk, dims.chunk, dims.chunk)
+    found = {}
+    for c in _clusters_with_loop_bodies(g):
+        if tuple(c.body_shape) == body:
+            k = emit_cluster(c)
+            found.setdefault(re.sub(r"^fused_map\d+_", "", k.name), (c, k))
+    return body, found
+
+
+@pytest.mark.parametrize("kernel", ["sub_where_exp_mul", "sub_where_exp", "mul"])
+def test_hybrid_ssd_kernels_broadcast_in_vmem(hybrid_ssd_kernels, sds, kernel):
+    """Each SSD kernel takes its cumulative-decay rows and columns, the
+    causal (Q, Q) mask and C B^T (one for all 64 heads) at their own
+    sizes and broadcasts them per block in VMEM: it compiles through
+    Mosaic, and no broadcast at the body's size is left in the HLO."""
+    body, found = hybrid_ssd_kernels
+    cluster, k = found[kernel]
+    assert k.broadcast_materialized == 0 and k.broadcast_in_kernel >= 2
+    avals = [sds(n.abstract.shape, n.abstract.dtype) for n in cluster.inputs]
+    text = _compiled_text(k.pallas_compiled, *avals)
+    assert _KERNEL in text
+    size = math.prod(body)
+    assert not [
+        n for n, dims, op, _ in _HLO_INSTR.findall(text)
+        if op == "broadcast" and math.prod(int(d) for d in dims.split(",") if d) == size
+    ]
 
 
 def test_lm_reduce_cluster_over_vmem_declines(lm_clusters):
