@@ -23,11 +23,11 @@ from repro.core import P, parse_function
 from repro.core.api import compile_pipeline
 from repro.core.fusion import DeclineReason, partition_graph
 from repro.core.infer import abstract_of_value
+from repro.core.ir import Constant, Graph, toposort
+from repro.core.primitives import LOOP_GRAPH_ARGS
 from repro.kernels.codegen import emit_cluster, emit_cluster_explained
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rmsnorm import rmsnorm_bwd, rmsnorm_fwd
-from repro.core.ir import Constant, Graph, toposort
-from repro.core.primitives import LOOP_GRAPH_ARGS
 from repro.kernels.ssd_scan import ssd_scan_fwd
 from repro.launch.myia_hybrid import HybridDims, init_hybrid_params, make_hybrid_train_step
 from repro.launch.myia_step import MyiaLMDims, make_myia_train_step
@@ -194,7 +194,7 @@ def hybrid_ssd_kernels():
 
 
 @pytest.mark.parametrize("kernel", ["sub_where_exp_mul", "sub_where_exp", "mul"])
-def test_hybrid_ssd_kernels_broadcast_in_vmem(hybrid_ssd_kernels, sds, kernel):
+def test_hybrid_ssd_kernels_broadcast_in_vmem(sds, hybrid_ssd_kernels, kernel):
     """Each SSD kernel takes its cumulative-decay rows and columns, the
     causal (Q, Q) mask and C B^T (one for all 64 heads) at their own
     sizes and broadcasts them per block in VMEM: it compiles through
