@@ -98,6 +98,7 @@ echo "== explain + profile smoke (every job: reports stay structured, profiler s
 pyfile <<'PY'
 import jax.numpy as jnp
 from repro.core.api import CompileOptions, grad
+from repro.core.lowering import lower_graph
 from repro.core.primitives import reduce_sum as _rsum, tanh as _tanh
 from repro.obs import Profiler, profiling
 from repro.obs.explain import ExplainReport
@@ -106,8 +107,7 @@ def _loss(w1, w2, x):
     h = _tanh(x @ w1)
     return _rsum(_tanh(h @ w2), None, False)
 
-opts = CompileOptions(fuse=True, profile=True)
-df = grad(_loss, (0, 1), options=opts)
+df = grad(_loss, (0, 1), options=CompileOptions(fuse=True))
 args = (jnp.ones((8, 8), jnp.float32) * 0.1,
         jnp.ones((8, 8), jnp.float32) * 0.1,
         jnp.ones((4, 8), jnp.float32))
@@ -126,12 +126,14 @@ for n in fus["nodes"]:
     if n["decision"] == "unfused":
         assert isinstance(n.get("reason"), dict) and "kind" in n["reason"], n
 
-# profile: armed run of the fused workload lands on the roofline scale
-df(*args)  # warm: compile outside the profiled window
+# profile: armed eager run of the instrumented fused lowering of the same
+# optimized graph lands on the roofline scale
+fn = lower_graph(df.optimized_graph(*args), fuse=True, profile=True)
+fn(*args)  # warm: trace the kernels outside the profiled window
 prof = Profiler()
 with profiling(prof):
     for _ in range(3):
-        df(*args)
+        fn(*args)
 assert prof.sites, "armed profiler recorded no launches"
 agg = prof.aggregate()
 fr = agg["roofline_fraction"]

@@ -13,8 +13,8 @@
   ``grad`` is also a *macro*: used inside ``@myia`` code it expands at parse
   time (paper Figure 1: "After the grad macro is expanded …").
 
-Compile configuration — migration note
---------------------------------------
+Compile configuration
+---------------------
 
 All four entry points (and ``compile_pipeline``) take a single frozen
 :class:`CompileOptions` carrying the full tier set::
@@ -24,15 +24,8 @@ All four entry points (and ``compile_pipeline``) take a single frozen
     f  = myia(fn, options=opts)
     df = grad(fn, options=opts)          # same tiers — full parity
 
-The historical per-kwarg spelling (``myia(fn, fuse=True, ...)``) still
-works through a shim that assembles the same ``CompileOptions`` and emits
-a ``DeprecationWarning``; both spellings produce identical compiled
-artifacts (same structural hash — pinned by tests).  ``checkpoint_policy``
-(loop-adjoint recording: ``"auto"`` / ``"save_all"`` / ``"recompute"`` /
-int slot count, see ``repro.core.ad``) is only reachable through
-``CompileOptions``.  ``MyiaFunction.options`` holds the resolved object;
-the legacy attributes (``.fuse``, ``.program_cache``, ...) remain as
-delegating properties.
+``options=None`` means ``CompileOptions()``.  ``MyiaFunction.options``
+holds the object the function was built with.
 
 ``grad``/``value_and_grad``/``vjp`` of a program containing loops or
 recursion defer the AD transform to specialization time: the primal runs
@@ -46,7 +39,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import hashlib
-import warnings
 from typing import Any, Callable
 
 import jax
@@ -83,8 +75,7 @@ __all__ = [
 class CompileOptions:
     """One immutable object carrying every compile tier configuration.
 
-    Replaces the seven loose kwargs that accreted onto the entry points;
-    every entry point accepts ``options=CompileOptions(...)`` and threads
+    Every entry point accepts ``options=CompileOptions(...)`` and threads
     it whole, so each tier (fusion, patterns, SPMD, AOT cache, tracing,
     loop-adjoint checkpointing) is reachable from *all* of
     ``myia``/``grad``/``value_and_grad``/``vjp``.
@@ -101,7 +92,6 @@ class CompileOptions:
     ``graph_cache``      ``None``    optimized-graph tier (skips optimize warm)
     ``trace``            ``None``    observability (``Tracer`` spans)
     ``checkpoint_policy``  ``"auto"``  loop-adjoint memory/recompute point
-    ``profile``          ``False``   runtime profiler (eager instrumented launch)
     ===================  ==========  =============================================
 
     ``graph_cache`` and ``program_cache`` usually point at the *same*
@@ -132,47 +122,7 @@ class CompileOptions:
     #: loop-adjoint carry recording: "auto" / "save_all" / "recompute"
     #: or an int slot count (see ``repro.core.ad._CHECKPOINT_SLOTS``)
     checkpoint_policy: str | int = "auto"
-    #: runtime-profiler tier — when True AND a ``repro.obs.profile``
-    #: Profiler is armed, calls with concrete args execute an instrumented
-    #: eager lowering that records per-launch wall time + bytes moved;
-    #: disarmed (or False) the ordinary jit tiers run untouched
-    profile: bool = False
 
-
-_UNSET: Any = object()
-
-#: the legacy kwargs the shim still accepts (checkpoint_policy and
-#: graph_cache are newer than the shim and reachable only through
-#: CompileOptions — no legacy spelling to support)
-_LEGACY_FIELDS = (
-    "backend", "opt", "fuse", "patterns", "in_specs", "program_cache", "trace",
-)
-
-
-def _resolve_options(
-    options: CompileOptions | None, caller: str, legacy: dict[str, Any]
-) -> CompileOptions:
-    """The legacy-kwarg shim: fold explicitly-passed per-tier kwargs into
-    a ``CompileOptions`` (with a ``DeprecationWarning``), or pass the
-    given options object through.  Mixing both spellings is an error —
-    silently preferring one would mask a config bug."""
-    passed = {k: v for k, v in legacy.items() if v is not _UNSET}
-    if options is not None:
-        if passed:
-            raise TypeError(
-                f"{caller}() got both options= and legacy compile kwargs "
-                f"{sorted(passed)}; pass everything in CompileOptions"
-            )
-        return options
-    if passed:
-        warnings.warn(
-            f"{caller}({', '.join(sorted(passed))}=...) is deprecated; pass "
-            f"options=CompileOptions(...) instead",
-            DeprecationWarning,
-            stacklevel=3,
-        )
-        return CompileOptions(**passed)
-    return CompileOptions()
 
 #: XLA options for the throwaway first-call executable (tiered compilation):
 #: skip backend optimizations and expensive LLVM passes — on CPU this
@@ -214,11 +164,9 @@ def compile_pipeline(
     graph: Graph,
     example_args: tuple | None = None,
     *,
-    opt: bool = True,
     infer_types: bool = True,
     engine: str = "worklist",
     stats: OptStats | None = None,
-    patterns: bool = False,
     loops: bool = True,
     options: CompileOptions | None = None,
     snapshot: Callable[[str, Graph], None] | None = None,
@@ -226,21 +174,22 @@ def compile_pipeline(
     """inline → infer → optimize → loop-lower, on a private clone of
     ``graph``.
 
-    ``options`` (a :class:`CompileOptions`) supplies ``opt``/``patterns``
-    when given — the same object the entry points thread — while the
-    pipeline-internal knobs (``engine``, ``stats``, ``infer_types``,
-    ``loops``) stay explicit kwargs.
-    ``engine`` / ``stats`` are forwarded to :func:`repro.core.opt.optimize`
-    (all optimize calls share the one stats object).  ``patterns=True``
-    additionally enables the kernel-pattern rules of the fusion tier
-    (rmsnorm / softmax-attention subgraphs rewritten to the hand-written
-    Pallas primitives registered in ``repro.kernels.ops``) in the
-    shape-directed pass.  ``loops=True`` (the closure-elimination tier)
-    rewrites residual tail-recursive families into ``while_loop`` /
-    ``scan_loop`` primitive applies (``repro.core.closure``) so parsed
-    loops lower instead of falling back to the VM; when ``stats`` is
-    given, any remaining fallback reasons land in
-    ``stats.fallback_reasons`` (structured, see ``FallbackReason``).
+    ``options`` (a :class:`CompileOptions`, default ``CompileOptions()``)
+    supplies ``opt``, ``patterns`` and ``graph_cache`` — the same object
+    the entry points thread — while the pipeline-internal knobs
+    (``engine``, ``stats``, ``infer_types``, ``loops``) stay explicit
+    kwargs.  ``engine`` / ``stats`` are forwarded to
+    :func:`repro.core.opt.optimize` (all optimize calls share the one
+    stats object).  ``options.patterns`` additionally enables the
+    kernel-pattern rules of the fusion tier (rmsnorm / softmax-attention
+    subgraphs rewritten to the hand-written Pallas primitives registered
+    in ``repro.kernels.ops``) in the shape-directed pass.  ``loops=True``
+    (the closure-elimination tier) rewrites residual tail-recursive
+    families into ``while_loop`` / ``scan_loop`` primitive applies
+    (``repro.core.closure``) so parsed loops lower instead of falling
+    back to the VM; when ``stats`` is given, any remaining fallback
+    reasons land in ``stats.fallback_reasons`` (structured, see
+    ``FallbackReason``).
 
     ``snapshot`` (the explain layer's IR-dump hook) is called as
     ``snapshot(stage, graph)`` after each pipeline stage — ``cloned`` /
@@ -248,10 +197,9 @@ def compile_pipeline(
     ``graph_cache_hit`` when the optimized-graph tier answers.  None (the
     default) costs nothing.
     """
-    if options is not None:
-        opt = options.opt
-        patterns = options.patterns
-    gcache = options.graph_cache if options is not None else None
+    if options is None:
+        options = CompileOptions()
+    opt, patterns, gcache = options.opt, options.patterns, options.graph_cache
     # every phase below opens a span (see docs/observability.md for the
     # taxonomy); disarmed, span() is a single global None-check
     with obs_trace.span("compile_pipeline", graph=graph.name):
@@ -334,43 +282,6 @@ def compile_pipeline(
         return g
 
 
-def _wrap_profiled(inner: Callable, g: Graph, fuse: bool) -> Callable:
-    """The ``CompileOptions.profile`` tier: while a
-    :class:`repro.obs.profile.Profiler` is armed and the args are
-    concrete, route calls to a lazily-built *instrumented eager* lowering
-    (``lower_graph(profile=True)``) so every launch records wall time and
-    bytes moved.  Disarmed — or under an outer jit trace, or when the
-    graph doesn't lower — the wrapped runner is a single module-global
-    None-check away from the ordinary tiers."""
-    from repro.obs import profile as obs_profile
-
-    from .lowering import LoweringError, lower_graph
-
-    state: dict[str, Any] = {}
-
-    def runner(*args):
-        if obs_profile._ACTIVE is None or any(
-            isinstance(a, jax.core.Tracer) for a in args
-        ):
-            return inner(*args)
-        pfn = state.get("fn", _UNSET)
-        if pfn is _UNSET:
-            try:
-                pfn = lower_graph(g, fuse=fuse, profile=True)
-            except LoweringError:
-                pfn = None  # VM-fallback graph: nothing to instrument
-            state["fn"] = pfn
-        if pfn is None:
-            return inner(*args)
-        return pfn(*args)
-
-    runner.profiled = True
-    for attr in ("lowered", "jitted", "aot", "cache_key", "degraded"):
-        if hasattr(inner, attr):
-            setattr(runner, attr, getattr(inner, attr))
-    return runner
-
-
 def _apply_transform(
     g: Graph, t: tuple, example: tuple | None, policy: str | int
 ) -> Graph:
@@ -403,21 +314,13 @@ class MyiaFunction:
         options: CompileOptions | None = None,
         name: str | None = None,
         transforms: tuple = (),
-        backend: Any = _UNSET,
-        opt: Any = _UNSET,
-        fuse: Any = _UNSET,
-        patterns: Any = _UNSET,
-        in_specs: Any = _UNSET,
-        program_cache: Any = _UNSET,
-        trace: Any = _UNSET,
     ) -> None:
         if fn is None and graph is None:
             raise ValueError("need fn or graph")
         self._fn = fn
         self._graph = graph
-        #: the resolved :class:`CompileOptions` — the single source of
-        #: truth for every tier (the legacy per-tier attributes below are
-        #: delegating properties over this object):
+        #: the :class:`CompileOptions` — the single source of truth for
+        #: every tier:
         #:
         #: * ``program_cache`` — AOT tier: a ProgramCache makes compiled
         #:   specializations durable (``jit(...).lower().compile()`` +
@@ -430,13 +333,7 @@ class MyiaFunction:
         #:   dynamic extent of every specialization.
         #: * ``checkpoint_policy`` — loop-adjoint carry recording (used
         #:   when pending AD ``transforms`` resolve at specialization).
-        self.options = _resolve_options(
-            options, "MyiaFunction", {
-                "backend": backend, "opt": opt, "fuse": fuse,
-                "patterns": patterns, "in_specs": in_specs,
-                "program_cache": program_cache, "trace": trace,
-            },
-        )
+        self.options = options if options is not None else CompileOptions()
         #: pending AD transforms, applied at specialization time *after*
         #: the primal has run the loop-lowering pipeline: a tuple of
         #: ``("grad", wrt)`` / ``("vag", wrt)`` / ``("vjp",)`` stages.
@@ -448,25 +345,6 @@ class MyiaFunction:
         self.__name__ = name or (fn.__name__ if fn is not None else graph.name)
         if fn is not None:
             functools.update_wrapper(self, fn, updated=())
-
-    # -- legacy attribute surface (delegates to .options) -----------------
-    def _opt_property(field):  # noqa: N805 — descriptor factory, not a method
-        def get(self):
-            return getattr(self.options, field)
-
-        def set_(self, value):
-            self.options = dataclasses.replace(self.options, **{field: value})
-
-        return property(get, set_, doc=f"delegates to CompileOptions.{field}")
-
-    backend = _opt_property("backend")
-    opt = _opt_property("opt")
-    fuse = _opt_property("fuse")
-    patterns = _opt_property("patterns")
-    in_specs = _opt_property("in_specs")
-    program_cache = _opt_property("program_cache")
-    trace = _opt_property("trace")
-    del _opt_property
 
     # -- graph access ---------------------------------------------------
     @property
@@ -528,7 +406,7 @@ class MyiaFunction:
         active, or the context's mesh is abstract (spec-resolution tests).
         A trivial 1×1 mesh still takes the spmd path — that identity with
         the single-device tier is pinned by tests."""
-        if self.in_specs is None or self.backend != "jax":
+        if self.options.in_specs is None or self.options.backend != "jax":
             return None
         from repro.parallel import current_mesh_context
 
@@ -538,7 +416,8 @@ class MyiaFunction:
         return ctx.mesh
 
     def specialize(self, args: tuple) -> Callable:
-        if self.fuse:
+        o = self.options
+        if o.fuse:
             # fused runners bake the kernel mode in at trace time (the
             # FusedKernel dispatch runs under jit), so a mode switch must
             # select a different specialization, not reuse a stale trace
@@ -554,28 +433,24 @@ class MyiaFunction:
         from .jax_backend import mesh_descriptor
 
         meshkey = mesh_descriptor(mesh)
-        key = (self.backend, self.fuse, self.patterns, mode, meshkey, self._sigkey(args))
+        key = (o.backend, o.fuse, o.patterns, mode, meshkey, self._sigkey(args))
         hit = self._specializations.get(key)
         if hit is not None:
             return hit
-        with obs_trace.tracing(self.trace), obs_trace.span(
-            "specialize", graph=self.__name__, fuse=self.fuse
+        with obs_trace.tracing(o.trace), obs_trace.span(
+            "specialize", graph=self.__name__, fuse=o.fuse
         ):
             try:
                 example = tuple(abstract_of_value(a) for a in args)
             except InferenceError:
                 example = None  # e.g. a list static: skip inference, VM handles it
             base = self._resolved_graph(example) if self.transforms else self.graph
-            g = compile_pipeline(base, example, options=self.options)
+            g = compile_pipeline(base, example, options=o)
             runner = None
             if mesh is not None:
                 runner = self._make_spmd_runner(g, args, mesh)
-                # (spmd runners are never profile-wrapped: collectives
-                # only execute under shard_map, not eagerly)
             if runner is None:
                 runner = self._make_runner(g, args)
-                if self.options.profile and self.backend == "jax":
-                    runner = _wrap_profiled(runner, g, self.fuse)
             self._specializations[key] = runner
             return runner
 
@@ -588,12 +463,13 @@ class MyiaFunction:
         if not all(is_array_like(a) for a in example_args):
             return None
         try:
-            return compile_graph_spmd(g, mesh, self.in_specs, fuse=self.fuse)
+            return compile_graph_spmd(g, mesh, self.options.in_specs, fuse=self.options.fuse)
         except SpmdError:
             return None
 
     def _make_runner(self, g: Graph, example_args: tuple) -> Callable:
-        if self.backend == "vm":
+        o = self.options
+        if o.backend == "vm":
             def runner(*args):
                 return VM().call(g, args)
 
@@ -602,10 +478,10 @@ class MyiaFunction:
         # jax backend: arrays are dynamic (traced), everything else static.
         dyn_idx = [i for i, a in enumerate(example_args) if is_array_like(a)]
         static = {i: a for i, a in enumerate(example_args) if i not in set(dyn_idx)}
-        lowered = try_lower(g, fuse=self.fuse)
+        lowered = try_lower(g, fuse=o.fuse)
 
         if (
-            self.program_cache is not None
+            o.program_cache is not None
             and lowered is not None
             and len(dyn_idx) == len(example_args)
             and not any(isinstance(a, jax.core.Tracer) for a in example_args)
@@ -619,8 +495,8 @@ class MyiaFunction:
             from .serialize import SerializeError
 
             try:
-                aot = self.program_cache.load_or_compile(
-                    g, example_args, fuse=self.fuse, lowered_fn=lowered
+                aot = o.program_cache.load_or_compile(
+                    g, example_args, fuse=o.fuse, lowered_fn=lowered
                 )
             except SerializeError:
                 pass  # not durable (exotic constants): ordinary tiers
@@ -631,7 +507,7 @@ class MyiaFunction:
                 # optimized graph eagerly — no XLA on the critical path,
                 # slow but correct — and the downgrade is counted so a
                 # serving fleet can alarm on it.
-                self.program_cache.stats.vm_fallbacks += 1
+                o.program_cache.stats.vm_fallbacks += 1
 
                 def runner(*args):
                     return VM().call(g, args)
@@ -739,19 +615,11 @@ def myia(
     fn: Callable | None = None,
     *,
     options: CompileOptions | None = None,
-    backend: Any = _UNSET,
-    opt: Any = _UNSET,
-    fuse: Any = _UNSET,
-    patterns: Any = _UNSET,
-    in_specs: Any = _UNSET,
-    program_cache: Any = _UNSET,
-    trace: Any = _UNSET,
 ):
     """Decorator: compile ``fn`` (pure Python subset) through the pipeline.
 
     Tier configuration arrives as one ``options=CompileOptions(...)``
-    (the per-kwarg spelling still works but is deprecated — see the
-    module docstring's migration note):
+    (``None``: ``CompileOptions()``):
 
     * ``fuse=True`` turns on the fusion tier (clustered regions run as
       generated Pallas kernels); ``patterns=True`` additionally rewrites
@@ -770,13 +638,9 @@ def myia(
       tier: every specialization compiles with the tracer armed, so
       pipeline phases, inline waves and XLA compiles land in its buffer.
     """
-    opts = _resolve_options(options, "myia", {
-        "backend": backend, "opt": opt, "fuse": fuse, "patterns": patterns,
-        "in_specs": in_specs, "program_cache": program_cache, "trace": trace,
-    })
 
     def wrap(f: Callable) -> MyiaFunction:
-        return MyiaFunction(f, options=opts)
+        return MyiaFunction(f, options=options)
 
     return wrap(fn) if fn is not None else wrap
 
@@ -822,7 +686,7 @@ def _macro_expand_vag(parser, block, ast_args):
 
 
 def _transform_entry(
-    fn: Any, transform: tuple, opts: CompileOptions, caller: str
+    fn: Any, transform: tuple, options: CompileOptions | None
 ) -> MyiaFunction:
     """Shared construction path of the AD entry points.
 
@@ -833,6 +697,7 @@ def _transform_entry(
     primal runs the loop-lowering pipeline — with the concrete signature
     — before J sees it; that ordering is what keeps grad-of-loop programs
     off the VM.  Chaining (``grad(grad(f))``) extends the pending tuple."""
+    opts = options if options is not None else CompileOptions()
     if isinstance(fn, MyiaFunction) and fn.transforms:
         return MyiaFunction(
             fn=fn._fn, graph=fn._graph, options=opts,
@@ -854,24 +719,13 @@ def grad(
     wrt: int | tuple[int, ...] = 0,
     *,
     options: CompileOptions | None = None,
-    backend: Any = _UNSET,
-    opt: Any = _UNSET,
-    fuse: Any = _UNSET,
-    patterns: Any = _UNSET,
-    in_specs: Any = _UNSET,
-    program_cache: Any = _UNSET,
-    trace: Any = _UNSET,
 ):
     """Reverse-mode gradient of a scalar-output function (paper §3.2).
 
     The adjoint takes the same arguments as ``fn``, so every tier in
     ``options`` (SPMD ``in_specs``, the AOT ``program_cache``, ``trace``)
     carries over unchanged — full parity with ``myia``."""
-    opts = _resolve_options(options, "grad", {
-        "backend": backend, "opt": opt, "fuse": fuse, "patterns": patterns,
-        "in_specs": in_specs, "program_cache": program_cache, "trace": trace,
-    })
-    return _transform_entry(fn, ("grad", wrt), opts, "grad")
+    return _transform_entry(fn, ("grad", wrt), options)
 
 
 def value_and_grad(
@@ -879,38 +733,12 @@ def value_and_grad(
     wrt: int | tuple[int, ...] = 0,
     *,
     options: CompileOptions | None = None,
-    backend: Any = _UNSET,
-    opt: Any = _UNSET,
-    fuse: Any = _UNSET,
-    patterns: Any = _UNSET,
-    in_specs: Any = _UNSET,
-    program_cache: Any = _UNSET,
-    trace: Any = _UNSET,
 ):
-    opts = _resolve_options(options, "value_and_grad", {
-        "backend": backend, "opt": opt, "fuse": fuse, "patterns": patterns,
-        "in_specs": in_specs, "program_cache": program_cache, "trace": trace,
-    })
-    return _transform_entry(fn, ("vag", wrt), opts, "value_and_grad")
+    return _transform_entry(fn, ("vag", wrt), options)
 
 
-def vjp(
-    fn: Any,
-    *,
-    options: CompileOptions | None = None,
-    backend: Any = _UNSET,
-    opt: Any = _UNSET,
-    fuse: Any = _UNSET,
-    patterns: Any = _UNSET,
-    in_specs: Any = _UNSET,
-    program_cache: Any = _UNSET,
-    trace: Any = _UNSET,
-):
-    opts = _resolve_options(options, "vjp", {
-        "backend": backend, "opt": opt, "fuse": fuse, "patterns": patterns,
-        "in_specs": in_specs, "program_cache": program_cache, "trace": trace,
-    })
-    return _transform_entry(fn, ("vjp",), opts, "vjp")
+def vjp(fn: Any, *, options: CompileOptions | None = None):
+    return _transform_entry(fn, ("vjp",), options)
 
 
 grad.__is_myia_macro__ = True

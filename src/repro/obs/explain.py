@@ -459,7 +459,6 @@ def _options_section(options: Any) -> dict:
         "opt": options.opt,
         "fuse": options.fuse,
         "patterns": options.patterns,
-        "profile": getattr(options, "profile", False),
         "checkpoint_policy": str(options.checkpoint_policy),
         "in_specs": repr(options.in_specs) if options.in_specs is not None else None,
         "program_cache": options.program_cache is not None,

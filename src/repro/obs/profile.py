@@ -32,10 +32,9 @@ Timing semantics: a launch is timed eagerly — the hook calls the op,
 blocks on the result (``jax.block_until_ready``), and stamps the wall
 clock.  Under a ``jax.jit`` trace the Python hook would run once at trace
 time and measure nothing, so the instrumented lowering
-(``lower_graph(g, profile=True)``) is only executed *eagerly* by the
-profiled runner (``CompileOptions.profile``); hooks also pass tracer
-arguments straight through, so an armed profiler never corrupts an outer
-jit trace.
+(``lower_graph(g, profile=True)``) is called *eagerly*, outside any jit;
+hooks also pass tracer arguments straight through, so an armed profiler
+never corrupts an outer jit trace.
 """
 
 from __future__ import annotations

@@ -60,9 +60,9 @@ def test_st_grad_matches_jax_grad(name, x, a, b, c):
 )
 def test_optimizer_preserves_value_and_grad(name, x, a):
     fn = _FNS[name]
-    v1 = myia.myia(fn, opt=False)(x, a, 0.5, -0.25)
-    v2 = myia.myia(fn, opt=True)(x, a, 0.5, -0.25)
+    v1 = myia.myia(fn, options=myia.CompileOptions(opt=False))(x, a, 0.5, -0.25)
+    v2 = myia.myia(fn, options=myia.CompileOptions(opt=True))(x, a, 0.5, -0.25)
     np.testing.assert_allclose(float(v1), float(v2), rtol=1e-5, atol=1e-6)
-    g1 = myia.grad(fn, opt=False)(x, a, 0.5, -0.25)
-    g2 = myia.grad(fn, opt=True)(x, a, 0.5, -0.25)
+    g1 = myia.grad(fn, options=myia.CompileOptions(opt=False))(x, a, 0.5, -0.25)
+    g2 = myia.grad(fn, options=myia.CompileOptions(opt=True))(x, a, 0.5, -0.25)
     np.testing.assert_allclose(float(g1), float(g2), rtol=1e-5, atol=1e-6)

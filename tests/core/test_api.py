@@ -21,7 +21,7 @@ class TestSigkeyUnhashableStatics:
 
     @pytest.mark.parametrize("backend", ["vm", "jax"])
     def test_call_with_list_static(self, backend):
-        fn = myia.myia(_scale_by_first, backend=backend)
+        fn = myia.myia(_scale_by_first, options=myia.CompileOptions(backend=backend))
         out = fn(jnp.ones(3), [2.0, 3.0])
         np.testing.assert_allclose(np.asarray(out), 2.0 * np.ones(3))
         # second call must hit the specialization cache, not crash on it

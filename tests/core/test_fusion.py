@@ -433,7 +433,7 @@ class TestApiTier:
     def test_myia_fuse_flag_end_to_end(self):
         w1, w2, x = CORPUS[2][4]
         plain = myia.grad(_two_layer, (0, 1))
-        fused = myia.grad(_two_layer, (0, 1), fuse=True)
+        fused = myia.grad(_two_layer, (0, 1), options=myia.CompileOptions(fuse=True))
         r0, r1 = plain(w1, w2, x), fused(w1, w2, x)
         for u, v in zip(_flat(r0), _flat(r1)):
             np.testing.assert_array_equal(np.asarray(u), np.asarray(v))
@@ -457,7 +457,7 @@ class TestApiTier:
 
     def test_fused_source_mentions_kernels(self):
         w1, w2, x = CORPUS[2][4]
-        fused = myia.grad(_two_layer, (0, 1), fuse=True)
+        fused = myia.grad(_two_layer, (0, 1), options=myia.CompileOptions(fuse=True))
         g = fused.optimized_graph(w1, w2, x)
         fn = lower_graph(g, fuse=True)
         assert "_fused_" in fn.__lowered_source__
@@ -505,7 +505,7 @@ class TestLoweringSatellites:
         """A fused runner bakes the kernel mode in at trace time, so
         flipping set_kernel_mode must select a fresh specialization."""
         w1, w2, x = CORPUS[2][4]
-        fused = myia.grad(_two_layer, (0, 1), fuse=True)
+        fused = myia.grad(_two_layer, (0, 1), options=myia.CompileOptions(fuse=True))
         set_kernel_mode("ref")
         r_ref = fused(w1, w2, x)
         run_ref = fused.specialize((w1, w2, x))

@@ -14,7 +14,7 @@ Every adjoint here is checked three ways:
 * **VM-free**: ``analyze_blockers`` empty after the pipeline.
 
 Plus: grad-of-grad of while and scan, the ``checkpoint_policy`` ladder,
-the CompileOptions/legacy-kwarg parity matrix (same structural hash), a
+the CompileOptions surface of every entry point (same structural hash), a
 2×1 SPMD smoke of a loop adjoint, and an AOT warm restart of grad-of-scan
 with ``xla_compiles == 0`` across a process boundary (subprocess, slow).
 """
@@ -34,6 +34,7 @@ from repro.core import build_grad_graph, parse_function
 from repro.core.ad import build_value_and_grad_graph
 from repro.core.api import (
     CompileOptions,
+    MyiaFunction,
     compile_pipeline,
     grad,
     myia,
@@ -248,9 +249,8 @@ class TestCheckpointPolicy:
 
 # -- CompileOptions parity ---------------------------------------------------
 
-_LEGACY = {"opt": True, "fuse": False, "patterns": False}
-
 ENTRY_POINTS = {
+    "MyiaFunction": lambda fn, **kw: MyiaFunction(fn, **kw),
     "myia": lambda fn, **kw: myia(fn, **kw),
     "grad": lambda fn, **kw: grad(fn, 0, **kw),
     "value_and_grad": lambda fn, **kw: value_and_grad(fn, 0, **kw),
@@ -260,22 +260,25 @@ ENTRY_POINTS = {
 
 @pytest.mark.parametrize("entry", list(ENTRY_POINTS))
 class TestCompileOptionsParity:
-    def test_options_and_legacy_same_structural_hash(self, entry):
-        """Both spellings must yield the identical compiled artifact: the
-        optimized graphs of the two MyiaFunctions hash equal, and the
-        legacy spelling warns."""
+    def test_options_is_the_only_spelling(self, entry):
+        """``options`` is the one way to configure a compile: omitted, it
+        is ``CompileOptions()``; given, it arrives whole and two functions
+        built from the same object compile the identical artifact (equal
+        structural hashes); a former per-tier keyword is a TypeError, so a
+        stale caller fails instead of being silently ignored."""
         make = ENTRY_POINTS[entry]
-        via_options = make(p_scan_fold, options=CompileOptions(**_LEGACY))
-        with pytest.warns(DeprecationWarning):
-            via_legacy = make(p_scan_fold, **_LEGACY)
-        assert via_options.options == via_legacy.options
+        assert make(p_scan_fold).options == CompileOptions()
+        opts = CompileOptions(opt=True, fuse=False, patterns=False)
+        f1 = make(p_scan_fold, options=opts)
+        f2 = make(p_scan_fold, options=opts)
+        assert f1.options is opts
         args = (_X,) if entry != "vjp" else (_X, jnp.asarray(1.0, jnp.float32))
-        h1 = structural_hash(via_options.optimized_graph(*args))
-        h2 = structural_hash(via_legacy.optimized_graph(*args))
+        h1 = structural_hash(f1.optimized_graph(*args))
+        h2 = structural_hash(f2.optimized_graph(*args))
         assert h1 == h2
-        np.testing.assert_array_equal(
-            np.asarray(via_options(*args)), np.asarray(via_legacy(*args))
-        )
+        np.testing.assert_array_equal(np.asarray(f1(*args)), np.asarray(f2(*args)))
+        with pytest.raises(TypeError):
+            make(p_scan_fold, fuse=True)
 
     def test_full_tier_set_accepted(self, entry):
         """Every entry point takes the full tier set (grad/value_and_grad
@@ -289,11 +292,13 @@ class TestCompileOptionsParity:
         )
         f = make(p_scan_fold, options=opts)
         assert f.options is opts
-        assert f.in_specs == (None,)  # delegating property
+        assert f.options.in_specs == (None,)
 
     def test_mixing_spellings_rejected(self, entry):
+        """A per-tier keyword beside ``options=`` is no second spelling:
+        the call fails naming the keyword."""
         make = ENTRY_POINTS[entry]
-        with pytest.raises(TypeError, match="options="):
+        with pytest.raises(TypeError, match="fuse"):
             make(p_scan_fold, options=CompileOptions(), fuse=True)
 
 

@@ -169,7 +169,7 @@ class TestFallback:
         # power_rec is single-call affine non-tail recursion: the closure
         # tier rewrites it to count + reversed-accumulator loops, so it no
         # longer needs the VM (it used to be the documented fallback here)
-        fn = myia.myia(_use_recursion, backend="jax")
+        fn = myia.myia(_use_recursion, options=myia.CompileOptions(backend="jax"))
         assert float(fn(2.0)) == pytest.approx(32.0)
         assert fn.specialize((2.0,)).lowered is True
         gr = myia.grad(_use_recursion)
@@ -179,7 +179,7 @@ class TestFallback:
     def test_jax_backend_falls_back_to_vm(self):
         # a double self-call is beyond the loop rewriter (no single
         # back-edge): still the VM's job, traced under jit
-        fn = myia.myia(_use_double_rec, backend="jax")
+        fn = myia.myia(_use_double_rec, options=myia.CompileOptions(backend="jax"))
         assert float(fn(2.0)) == pytest.approx(16.0)
         runner = fn.specialize((2.0,))
         assert runner.lowered is False
@@ -204,7 +204,7 @@ class TestFallback:
 
 class TestTieredRunner:
     def test_first_call_tier0_matches_jitted(self):
-        fn = myia.myia(_two_layer, backend="jax")
+        fn = myia.myia(_two_layer, options=myia.CompileOptions(backend="jax"))
         w1 = jnp.ones((8, 8)) * 0.1
         w2 = jnp.ones((8, 8)) * 0.2
         x = jnp.ones((4, 8))
@@ -222,6 +222,6 @@ class TestTieredRunner:
         np.testing.assert_array_equal(np.asarray(r2), np.asarray(r3))
 
     def test_vm_backend_untouched(self):
-        fn = myia.myia(_poly, backend="vm")
+        fn = myia.myia(_poly, options=myia.CompileOptions(backend="vm"))
         assert float(fn(1.5)) == pytest.approx(_poly(1.5))
         assert fn.specialize((1.5,)).lowered is False

@@ -93,7 +93,8 @@ class TestScalarWorkloads:
     def test_cube_vm_backend_bit_match(self):
         """On the VM backend nothing ever leaves python floats, so the
         multiplicative chain matches the tape bit for bit."""
-        assert float(oo.oo_grad(cube)(1.3)) == float(myia.grad(cube, backend="vm")(1.3))
+        st = myia.grad(cube, options=myia.CompileOptions(backend="vm"))
+        assert float(oo.oo_grad(cube)(1.3)) == float(st(1.3))
 
     def test_value_and_grad_value_agrees(self):
         ov, og = oo.oo_value_and_grad(scalar_chain, wrt=0)(0.3, 0.7)
@@ -146,6 +147,6 @@ class TestArrayWorkloads:
         oo_loss, st_loss = _mlp_pair()
         w1, w2, x = _arrays((8, 8), (8, 8), (4, 8), seed=7)
         oo_g = oo.oo_grad(oo_loss, wrt=(0, 1))(w1, w2, x)
-        st_g = myia.grad(st_loss, wrt=(0, 1), fuse=True)(w1, w2, x)
+        st_g = myia.grad(st_loss, wrt=(0, 1), options=myia.CompileOptions(fuse=True))(w1, w2, x)
         for u, v in zip(oo_g, st_g):
             np.testing.assert_array_equal(np.asarray(u), np.asarray(v))

@@ -50,16 +50,16 @@ def qkv():
 
 class TestRmsnormPattern:
     def test_rewrites_to_kernel_prim(self, xw):
-        f = myia.myia(rms, patterns=True)
+        f = myia.myia(rms, options=myia.CompileOptions(patterns=True))
         assert _prims(f, *xw) == ["rmsnorm"]
 
     def test_commuted_spelling_matches(self, xw):
-        f = myia.myia(rms_commuted, patterns=True)
+        f = myia.myia(rms_commuted, options=myia.CompileOptions(patterns=True))
         assert _prims(f, *xw) == ["rmsnorm"]
 
     def test_numerics_match_reference(self, xw):
         x, w = xw
-        r_pat = myia.myia(rms, patterns=True)(x, w)
+        r_pat = myia.myia(rms, options=myia.CompileOptions(patterns=True))(x, w)
         r_ref = myia.myia(rms)(x, w)
         np.testing.assert_allclose(
             np.asarray(r_pat), np.asarray(r_ref), rtol=1e-5, atol=1e-6
@@ -78,7 +78,7 @@ class TestRmsnormPattern:
             return P.reduce_sum(x * P.rsqrt(ms + 1e-6) * w, (0, 1), False)
 
         g_ref = myia.grad(loss, (0, 1))(x, w)
-        g_pat = myia.grad(loss, (0, 1), patterns=True)(x, w)
+        g_pat = myia.grad(loss, (0, 1), options=myia.CompileOptions(patterns=True))(x, w)
         for u, v in zip(g_ref, g_pat):
             np.testing.assert_allclose(
                 np.asarray(u), np.asarray(v), rtol=1e-5, atol=1e-6
@@ -91,16 +91,17 @@ class TestRmsnormPattern:
             ms = P.reduce_sum(x * x, (1,), True) / 4.0  # D is 8
             return x * P.rsqrt(ms + 1e-6) * w
 
-        assert "rmsnorm" not in _prims(myia.myia(not_rms, patterns=True), *xw)
+        f = myia.myia(not_rms, options=myia.CompileOptions(patterns=True))
+        assert "rmsnorm" not in _prims(f, *xw)
 
 
 class TestAttentionPattern:
     def test_rewrites_to_flash_attention(self, qkv):
-        f = myia.myia(attn, patterns=True)
+        f = myia.myia(attn, options=myia.CompileOptions(patterns=True))
         assert _prims(f, *qkv) == ["flash_attention"]
 
     def test_numerics_match_reference(self, qkv):
-        r_pat = myia.myia(attn, patterns=True)(*qkv)
+        r_pat = myia.myia(attn, options=myia.CompileOptions(patterns=True))(*qkv)
         r_ref = myia.myia(attn)(*qkv)
         np.testing.assert_allclose(
             np.asarray(r_pat), np.asarray(r_ref), rtol=2e-5, atol=2e-6
@@ -118,7 +119,8 @@ class TestAttentionPattern:
             return (e / z) @ v
 
         args = tuple(jax.random.normal(jax.random.PRNGKey(i), (16, 8)) for i in range(3))
-        assert "flash_attention" not in _prims(myia.myia(attn2d, patterns=True), *args)
+        f = myia.myia(attn2d, options=myia.CompileOptions(patterns=True))
+        assert "flash_attention" not in _prims(f, *args)
 
     def test_off_by_default(self, qkv):
         assert "flash_attention" not in _prims(myia.myia(attn), *qkv)

@@ -19,8 +19,8 @@ def use_recursion(x):
 
 class TestRecursionOptimization:
     def test_value_all_backends(self):
-        assert myia.myia(use_recursion, backend="vm")(2.0) == 32.0
-        assert myia.myia(use_recursion, backend="jax")(2.0) == 32.0
+        assert myia.myia(use_recursion, options=myia.CompileOptions(backend="vm"))(2.0) == 32.0
+        assert myia.myia(use_recursion, options=myia.CompileOptions(backend="jax"))(2.0) == 32.0
 
     @pytest.mark.parametrize("opt", [False, True])
     @pytest.mark.parametrize("backend", ["vm", "jax"])
@@ -28,7 +28,7 @@ class TestRecursionOptimization:
         """d/dx x^5 at 2 = 80 — the optimizer must preserve it (this
         caught both the inline cycle-peeling hang and the unsound
         partial evaluation of frame-sensitive closure values)."""
-        g = myia.grad(use_recursion, backend=backend, opt=opt)
+        g = myia.grad(use_recursion, options=myia.CompileOptions(backend=backend, opt=opt))
         assert float(g(2.0)) == pytest.approx(80.0)
 
     def test_inline_pass_terminates_fast(self):

@@ -13,7 +13,7 @@ import pytest
 
 import repro.core.primitives as P
 from repro.core import build_grad_graph, parse_function
-from repro.core.api import compile_pipeline, value_and_grad
+from repro.core.api import CompileOptions, compile_pipeline, value_and_grad
 from repro.core.fusion import COLLECTIVES, classify, partition_graph
 from repro.core.infer import AArray, abstract_of_value
 from repro.core.ir import Apply, Constant
@@ -142,7 +142,7 @@ class TestShardGraph:
             return rec(n - 1)
 
         # a residually-recursive (non-lowerable) graph: skip optimization
-        g_raw = compile_pipeline(parse_function(rec), None, opt=False)
+        g_raw = compile_pipeline(parse_function(rec), None, options=CompileOptions(opt=False))
         with pytest.raises(SpmdError):
             shard_graph(g_raw, ((),), AXES)
 
@@ -222,7 +222,9 @@ class TestMesh1x1Identity:
         from repro.parallel import mesh_context
 
         args = _mlp_args()
-        vag = value_and_grad(_two_layer, (0, 1), in_specs=(None, None, ("data",)))
+        vag = value_and_grad(
+            _two_layer, (0, 1), options=CompileOptions(in_specs=(None, None, ("data",)))
+        )
         loss0, grads0 = vag(*args)
         assert not getattr(vag.specialize(args), "spmd", False)
         with mesh_context(make_local_mesh(1, 1), {}):
@@ -240,7 +242,9 @@ class TestMesh1x1Identity:
         from repro.parallel import mesh_context
 
         args = _mlp_args()
-        vag = value_and_grad(_two_layer, (0, 1), in_specs=(None, None, ("data",)))
+        vag = value_and_grad(
+            _two_layer, (0, 1), options=CompileOptions(in_specs=(None, None, ("data",)))
+        )
         mesh = AbstractMesh((16, 16), ("data", "model"))
         with mesh_context(mesh, {}):
             runner = vag.specialize(args)

@@ -221,28 +221,3 @@ def test_fused_kernel_self_times():
     fused_sites = [s for (_, kind), s in prof.sites.items() if kind == "fused"]
     assert len(fused_sites) == len(fn.__fused_kernels__)
 
-
-def test_profile_option_routes_through_instrumented_runner():
-    """CompileOptions(profile=True): disarmed calls use the ordinary
-    tiers; armed concrete calls execute the instrumented eager lowering
-    and agree numerically."""
-    import numpy as np
-
-    from repro.core import P
-    from repro.core.api import CompileOptions, grad
-
-    def loss(w, x):
-        h = P.tanh(x @ w)
-        return P.reduce_sum(h * h, (0, 1), False)
-
-    df = grad(loss, 0, options=CompileOptions(fuse=True, profile=True))
-    w = jnp.ones((8, 8), jnp.float32) * 0.1
-    x = jnp.ones((4, 8), jnp.float32)
-    cold = df(w, x)  # disarmed: ordinary tiers, nothing recorded
-    prof = Profiler()
-    with profiling(prof):
-        hot = df(w, x)
-    np.testing.assert_allclose(np.asarray(cold), np.asarray(hot), rtol=1e-5)
-    assert prof.sites, "armed profiled call recorded nothing"
-    agg = prof.aggregate()
-    assert agg["roofline_fraction"] is None or 0.0 < agg["roofline_fraction"] <= 1.0
