@@ -19,6 +19,11 @@ annotations):
   smaller than the body shape, so they prepare kernel operands),
 * **reduction**  — ``reduce_sum`` / ``reduce_max`` / ``unbroadcast``;
   legal only as a cluster's *root* (the single output),
+* **window**     — ``slice_axis`` along a non-lane axis whose output has
+  a map cluster's body shape, over a cluster input or over a
+  ``pad_zeros_axis`` of one (:func:`window_axis`, rule 4; ``classify``
+  calls them opaque); the kernel reads the underlying operand once and
+  forms the shifted window in VMEM,
 * **opaque**     — everything else (matmul, reshape, tuple machinery,
   registered Pallas primitives, …): never fused, always a cluster
   boundary.
@@ -40,6 +45,19 @@ is legal by construction):
    cluster body shape (elementwise per block); broadcast members' static
    arguments (target shape / axes / keepdims) must be constants; a
    reduction root's axes/keepdims (or target shape) must be constants.
+4. **windows** — a map cluster (elementwise root) absorbs a window
+   ``slice_axis`` and, when every live user it has is such a slice in
+   the cluster along its axis, the ``pad_zeros_axis`` beneath it; the
+   operand under them (a window's *source*) is never absorbed, so it
+   stays a cluster input the kernel reads at its own shape.  A slice is
+   a view of its source, so rule 1 does not bind it: one whose value is
+   also used outside the cluster (and the pad beneath it) stays in the
+   graph for those users, and the kernel forms its own copy
+   (``Cluster.shared``); no cluster claims a window member, so others
+   may copy it too.  Window members do not count towards
+   ``min_cluster_size``: a cluster forms exactly when it would without
+   them, and :meth:`Cluster.without_windows` gives back that cluster,
+   the fallback when codegen cannot hold a window's whole axis in VMEM.
 
 Growth is greedy and maximal: roots are attempted in reverse topological
 order (consumers first), so a cluster reaches as far up its operand tree
@@ -53,6 +71,8 @@ from __future__ import annotations
 
 from typing import Any
 
+import numpy as np
+
 from .infer import AArray
 from .ir import Apply, Constant, Graph, Node, is_constant_graph, toposort
 from .primitives import COLLECTIVE_NAMES as COLLECTIVES, Primitive
@@ -61,6 +81,7 @@ __all__ = [
     "ELEMENTWISE",
     "BROADCAST",
     "REDUCTION",
+    "WINDOW",
     "COLLECTIVES",
     "DeclineReason",
     "classify",
@@ -68,6 +89,7 @@ __all__ = [
     "FusionPlan",
     "explain_partition",
     "partition_graph",
+    "window_axis",
 ]
 
 #: primitive names computed pointwise at the body shape
@@ -85,6 +107,9 @@ BROADCAST = frozenset({"broadcast_to", "unreduce"})
 
 #: primitives that reduce the body shape DOWN to the output shape
 REDUCTION = frozenset({"reduce_sum", "reduce_max", "unbroadcast"})
+
+#: primitives a map cluster reads as shifted windows of an operand
+WINDOW = frozenset({"slice_axis", "pad_zeros_axis"})
 
 
 class DeclineReason:
@@ -183,6 +208,28 @@ def classify(node: Node) -> str:
     return "opaque"
 
 
+def window_axis(node: Node) -> int | None:
+    """The axis of a ``slice_axis`` or ``pad_zeros_axis`` whose static
+    arguments are constant integers and whose axis (normalized) is not
+    the last, the lane axis; None for any other node."""
+    p = _prim_of(node)
+    shape = _shape_of(node)
+    if p is None or p.name not in WINDOW or not shape or len(node.args) != 4:
+        return None
+    static = [a.value if isinstance(a, Constant) else None for a in node.args[1:]]
+    if not all(isinstance(v, (int, np.integer)) and not isinstance(v, bool) for v in static):
+        return None
+    axis = int(static[0])
+    if not -len(shape) <= axis < len(shape) - 1:
+        return None
+    return axis % len(shape)
+
+
+def _body_size(order: list[Apply]) -> int:
+    """Members that compute at the body: all but the window members."""
+    return sum(_prim_of(n).name not in WINDOW for n in order)
+
+
 def _static_args_const(node: Apply) -> bool:
     """broadcast/reduction prims carry static config after the data arg:
     ``broadcast_to(x, shp)``, ``unreduce(x, shp, axes, keepdims)``,
@@ -196,7 +243,7 @@ class Cluster:
     the single-output ``root``; ``inputs`` are the external value edges in
     first-use order (constants excluded — codegen embeds those)."""
 
-    __slots__ = ("root", "members", "order", "inputs", "kind", "body_shape")
+    __slots__ = ("root", "members", "order", "inputs", "kind", "body_shape", "shared")
 
     def __init__(
         self,
@@ -205,6 +252,7 @@ class Cluster:
         inputs: list[Node],
         kind: str,
         body_shape: tuple[int, ...],
+        shared: frozenset[int] = frozenset(),
     ) -> None:
         self.root = root
         self.members = {n._id for n in order}
@@ -212,6 +260,10 @@ class Cluster:
         self.inputs = inputs
         self.kind = kind  # "map" (elementwise root) | "reduce" (reduction root)
         self.body_shape = body_shape
+        #: window members whose value is also used outside the cluster (a
+        #: slice, or the pad beneath one): the kernel forms its own copy,
+        #: and the node stays in the graph for those users
+        self.shared = shared
 
     @property
     def out_shape(self) -> tuple[int, ...]:
@@ -223,6 +275,20 @@ class Cluster:
 
     def __len__(self) -> int:
         return len(self.order)
+
+    @property
+    def windows(self) -> list[Apply]:
+        """The window ``slice_axis`` members, read in the kernel."""
+        return [n for n in self.order if n.fn.value.name == "slice_axis"]
+
+    def without_windows(self) -> Cluster:
+        """The cluster without its window members, as the partitioner
+        grows it when slices are opaque: their outputs become inputs."""
+        order = [n for n in self.order if n.fn.value.name not in WINDOW]
+        members = {n._id for n in order}
+        return Cluster(
+            self.root, order, _collect_inputs(order, members), self.kind, self.body_shape
+        )
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         prims = "+".join(n.fn.value.name for n in self.order)
@@ -249,7 +315,7 @@ class FusionPlan:
 
     @property
     def fused_nodes(self) -> int:
-        return sum(len(c) for c in self.clusters)
+        return sum(len(c) - len(c.shared) for c in self.clusters)
 
     @property
     def launches_before(self) -> int:
@@ -299,12 +365,37 @@ def _grow(
     work = list(root.args[:1]) if kind == "reduction" else (
         [] if kind == "broadcast" else list(root.args)
     )
+    sources: set[int] = set()  # operands under the windows: stay inputs
     while work:
         p = work.pop()
         if not isinstance(p, Apply) or p._id in members or p._id in assigned:
             continue
-        if p is graph.return_:
-            continue  # the return value must stay materialized
+        if p is graph.return_ or p._id in sources:
+            continue  # the return value must stay materialized; a source too
+        axis = window_axis(p) if kind == "elementwise" else None
+        if axis is not None:
+            # a slice is a view of its source: the kernel forms its own
+            # copy, and users outside the cluster keep the node (shared)
+            src = p.args[0]
+            if p.fn.value.name == "slice_axis":
+                if _shape_of(p) != body_shape:
+                    continue  # not a window of the body
+                if window_axis(src) is None or src.fn.value.name != "pad_zeros_axis":
+                    sources.add(src._id)
+            elif all(
+                u._id in members
+                and u.fn.value.name == "slice_axis"
+                and window_axis(u) == axis
+                for (u, _i) in p.users
+                if u._id in live
+            ):
+                sources.add(src._id)  # a pad beneath this cluster's windows
+            else:
+                continue
+            members.add(p._id)
+            order.append(p)
+            work.append(src)
+            continue
         cls = classify(p)
         if cls not in ("elementwise", "broadcast"):
             continue  # reductions are root-only; opaque never fuses
@@ -375,6 +466,13 @@ def classify_reason(node: Node) -> DeclineReason | None:
             DeclineReason.NON_CONST_STATIC,
             f"{p.name} static config (shape/axes/keepdims) is not constant",
         )
+    if p.name in WINDOW:
+        return DeclineReason(
+            DeclineReason.OPAQUE,
+            f"{p.name} fuses only as a window of a map cluster's input: a "
+            "body-shaped slice along a non-lane axis, or a pad whose every "
+            "user is such a slice in the cluster",
+        )
     if classify(node) == "opaque":
         return DeclineReason(
             DeclineReason.OPAQUE, f"{p.name} has no elementwise kernel body"
@@ -414,7 +512,7 @@ def explain_partition(
                 DeclineReason.EMPTY_BODY,
                 "body shape is rank-0/empty; no kernel to win",
             )
-        elif len(grown) < min_cluster_size:
+        elif _body_size(grown) < min_cluster_size:
             neighbors = any(
                 u._id in assigned for (u, _i) in node.users if u._id in live
             ) or any(
@@ -459,7 +557,7 @@ def _partition_graph_body(graph: Graph, min_cluster_size: int) -> FusionPlan:
         if root._id in assigned:
             continue
         grown = _grow(graph, root, assigned, live)
-        if grown is None or len(grown) < min_cluster_size:
+        if grown is None or _body_size(grown) < min_cluster_size:
             continue
         order = sorted(grown, key=lambda n: topo_index[n._id])  # producers first
         kind = "reduce" if classify(root) == "reduction" else "map"
@@ -467,9 +565,20 @@ def _partition_graph_body(graph: Graph, min_cluster_size: int) -> FusionPlan:
         body_shape = (
             _shape_of(root.args[0]) if kind == "reduce" else _shape_of(root)
         )
+        shared: set[int] = set()
+        for n in reversed(order):  # consumers first: a slice before its pad
+            if n.fn.value.name in WINDOW and any(
+                u._id in live and (u._id not in members or u._id in shared)
+                for (u, _i) in n.users
+            ):
+                shared.add(n._id)
         clusters.append(
-            Cluster(root, order, _collect_inputs(order, members), kind, body_shape)
+            Cluster(
+                root, order, _collect_inputs(order, members), kind, body_shape,
+                frozenset(shared),
+            )
         )
-        assigned |= members
+        # a window member stays free for other clusters to copy
+        assigned |= {n._id for n in order if n.fn.value.name not in WINDOW}
     clusters.sort(key=lambda c: topo_index[c.root._id])
     return FusionPlan(graph, clusters, len(topo))

@@ -158,6 +158,8 @@ def lower_graph(graph: Graph, *, fuse: bool = False, profile: bool = False) -> C
         sp.set(
             broadcast_in_kernel=sum(k.broadcast_in_kernel for k in kernels),
             broadcast_materialized=sum(k.broadcast_materialized for k in kernels),
+            window_in_kernel=sum(k.window_in_kernel for k in kernels),
+            window_materialized=sum(k.window_materialized for k in kernels),
         )
         return fn
 
@@ -176,12 +178,13 @@ def _lower_graph_body(graph: Graph, fuse: bool, profile: bool = False) -> Callab
             kernel = emit_cluster(cluster)
             if kernel is None:
                 continue  # declined: this cluster stays on the jnp path
+            cluster = kernel.cluster  # without its windows if they did not fit
             fused[cluster.root._id] = kernel
             cluster_of_root[cluster.root._id] = cluster
-            skip |= cluster.members - {cluster.root._id}
+            skip |= cluster.members - cluster.shared - {cluster.root._id}
         # the attached plan must account only for clusters that actually
         # emitted — declined ones save no launches
-        plan.clusters = [c for c in plan.clusters if c.root._id in fused]
+        plan.clusters = [cluster_of_root[c.root._id] for c in plan.clusters if c.root._id in fused]
 
     env: dict[str, Any] = {}
     if profile:

@@ -42,6 +42,19 @@ and sized to a VMEM budget, :data:`_VMEM_BUDGET`):
   enters as Pallas passes bools to Mosaic, int32 at its own size; a
   value narrower than 32 bits that is 1 on both tile axes enters widened
   to a lane, which Mosaic can broadcast.
+* **windows**: a map cluster's window members (``slice_axis`` along a
+  non-lane axis, and the ``pad_zeros_axis`` beneath it; see
+  ``repro.core.fusion``) run in the kernel as they are, on a block of
+  their **source**, which enters once at its own shape and whole along
+  the window axis.  The frame is then the body's own axes and every
+  block holds each window axis whole (whole rows, lanes blocked, when
+  it is the frame's second-to-last).  Since such a block cannot shrink
+  to pay for temporaries, the VMEM plan counts what Mosaic keeps in
+  scoped VMEM, the blocks of the operands spanning the tile and of the
+  output double-buffered, and one temporary per window.  When no block
+  fits, ``emit_cluster`` emits the cluster without its windows: the
+  slices stay outside, as the partitioner would leave them without the
+  rule.
 * **reduce clusters** (reduction root): one whole-array block (no grid)
   — the kernel computes the elementwise body and applies the reduction
   primitive with its static axes, so the floating-point reduction order
@@ -72,7 +85,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core.fusion import Cluster, DeclineReason, classify
+from repro.core.fusion import Cluster, DeclineReason, classify, window_axis
 from repro.core.infer import AArray
 from repro.core.ir import Apply, Constant, Node
 from repro.core.primitives import _norm_axes
@@ -104,6 +117,9 @@ class FusedKernel:
         "bytes_moved",
         "broadcast_in_kernel",
         "broadcast_materialized",
+        "window_in_kernel",
+        "window_materialized",
+        "cluster",
         "oracle",
         "pallas_interpret",
         "pallas_compiled",
@@ -123,6 +139,9 @@ class FusedKernel:
         bytes_moved: int = 0,
         broadcast_in_kernel: int = 0,
         broadcast_materialized: int = 0,
+        window_in_kernel: int = 0,
+        window_materialized: int = 0,
+        cluster: Cluster | None = None,
     ) -> None:
         self.name = name
         self.source = source
@@ -140,6 +159,14 @@ class FusedKernel:
         self.broadcast_in_kernel = broadcast_in_kernel
         #: operands the wrapper still expands to the body shape in HBM
         self.broadcast_materialized = broadcast_materialized
+        #: window slices the kernel forms in VMEM from their source
+        self.window_in_kernel = window_in_kernel
+        #: body-shaped non-lane slices that reach the kernel from outside,
+        #: materialized in HBM
+        self.window_materialized = window_materialized
+        #: the cluster the kernel computes: the one it was asked for, or
+        #: that cluster without its windows when they did not fit
+        self.cluster = cluster
         self.oracle = oracle
         self.pallas_interpret = pallas_interpret
         self.pallas_compiled = pallas_compiled
@@ -283,6 +310,9 @@ def emit_cluster_explained(
     generator cannot express the cluster — the structured verdict the
     explain layer reports per cluster."""
     got = _emit_cluster_impl(cluster)
+    if not isinstance(got, FusedKernel) and cluster.windows:
+        # the windows' whole axes did not fit: the slices stay outside
+        got = _emit_cluster_impl(cluster.without_windows())
     if isinstance(got, FusedKernel):
         return got, None
     return None, got
@@ -304,6 +334,15 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
     body: list[Apply] = []  # elementwise members (+ reduction root)
     for n in cluster.order:
         (pre if classify(n) == "broadcast" else body).append(n)
+    # window members (the slices and a pad beneath them) run in the kernel
+    # as they are, on a block that holds their axis whole: the source under
+    # each (a cluster input) and the axis it shifts along
+    windows: list[tuple[Node, int]] = []
+    for n in cluster.windows:
+        src = n.args[0]
+        if src._id in members:
+            src = src.args[0]
+        windows.append((src, window_axis(n)))
     if not body or body[-1] is not cluster.root:
         return DeclineReason(
             DeclineReason.CODEGEN,
@@ -460,18 +499,24 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
     widest, narrowest = max(sizes), min(sizes)
     # each operand as (slot, wrapper expr, shape it enters with, kind,
     # itemsize), of one kind: "full" (at the kernel's frame), "small" (at
-    # its own size, broadcast by the kernel per block in VMEM) or "wide"
-    # (a reduce cluster's, expanded to the body shape in HBM by the wrapper)
+    # its own size, broadcast by the kernel per block in VMEM), "source"
+    # (under windows, at its own size, whole along the window axes) or
+    # "wide" (a reduce cluster's, expanded to the body shape in HBM by the
+    # wrapper)
     operands: list[tuple[str, str, tuple, str, int]] = []
     if cluster.kind == "map":
         # each operand's shape left-padded to the body's rank (numpy's
         # broadcasting): every axis is then the body's or 1
         nd = len(body_shape)
         padded = [(1,) * (nd - len(shape)) + shape for _, _, shape, _, _ in found]
-        frame, shapes = _frame(body_shape, padded)
+        sources = {operand_slot[src._id] for src, _ in windows}
+        # a window shifts along the body's own axes: no 2-D frame then
+        frame, shapes = (body_shape, padded) if windows else _frame(body_shape, padded)
         for (slot, r, own, dtype, _), shape in zip(found, shapes):
             if shape == frame:
                 expr, kind = (r if own == frame else f"jnp.reshape({r}, {frame})"), "full"
+            elif slot in sources:
+                expr, kind = r, "source"
             else:
                 expr, kind = f"jnp.reshape({r}, {shape})", "small"
                 if shape[-2:] == (1, 1) and np.dtype(dtype).itemsize < 4:
@@ -491,14 +536,39 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
     kinds = [op[3] for op in operands]
     n_small = kinds.count("small")
     if cluster.kind == "map":
-        # operands spanning the whole tile (the frame's last two axes) and
-        # the output double-buffered, plus one temporary per body node and
-        # per in-kernel broadcast
         tile = frame[-2:]
-        n_tile = sum(op[2][-2:] == tile for op in operands)
-        elem_bytes = widest * (2 * (n_tile + 1) + len(body) + n_small)
         sub = _sublanes(narrowest)
-        got = _map_block(*tile, sub, elem_bytes)
+        # the block holds each window axis whole
+        waxes = {axis for _, axis in windows}
+        if windows:
+            # so it cannot shrink to pay for temporaries: it plans what
+            # Mosaic keeps in scoped VMEM, the blocks of the operands
+            # spanning the tile (a source at its whole extent) and of the
+            # output double-buffered, and one temporary per window
+            whole = math.prod(frame[a] for a in waxes)
+            n_buf = 1 + sum(
+                -(-math.prod(op[2][a] for a in waxes) // whole)
+                for op in operands
+                if op[2][-2:] == tile or op[3] == "source"
+            )
+            elem_bytes = widest * (2 * n_buf + len(windows))
+        else:
+            # operands spanning the whole tile (the frame's last two axes)
+            # and the output double-buffered, plus one temporary per body
+            # node and per in-kernel broadcast
+            n_tile = sum(op[2][-2:] == tile for op in operands)
+            elem_bytes = widest * (2 * (n_tile + 1) + len(body) + n_small)
+        # each element of a tile costs this much with the leading window
+        # axes held whole
+        per_tile = elem_bytes * math.prod(frame[a] for a in waxes if a < nd - 2)
+        if nd - 2 in waxes:
+            cap = _VMEM_BUDGET // per_tile
+            R, C = tile
+            got = (R, C) if R * C <= cap else (
+                (R, cap // R // _LANE * _LANE) if R * _LANE <= cap else None
+            )
+        else:
+            got = _map_block(*tile, sub, per_tile)
         if got is None:
             return DeclineReason(
                 DeclineReason.VMEM,
@@ -510,8 +580,9 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
             # whole tiles (each at its sublane- and lane-padded size): the
             # block takes as many as fit over the leading axes
             padded_tile = -(-got[0] // sub) * sub * (-(-got[1] // _LANE) * _LANE)
-            room = _VMEM_BUDGET // elem_bytes // padded_tile
-        block = (*_lead_block(frame[:-2], room), *got)
+            room = _VMEM_BUDGET // per_tile // padded_tile
+        outer = _lead_block(tuple(1 if a in waxes else d for a, d in enumerate(frame[:-2])), room)
+        block = (*(frame[a] if a in waxes else b for a, b in enumerate(outer)), *got)
         grid = [-(-f // b) for f, b in zip(frame, block)]
         lead = range(len(frame) - 2)
         names = [f"g{a}" for a in lead] + ["i", "j"]
@@ -590,6 +661,11 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
             ext = tuple(b if d > 1 else 1 for d, b in zip(shape, block))
             idx = ", ".join(n if d > 1 else "0" for d, n in zip(shape, names))
             in_specs.append(f"pl.BlockSpec({ext}, lambda {params}: ({idx}))")
+        elif kind == "source":
+            # whole along the window axes, with the grid along the others
+            ext = tuple(d if a in waxes else b for a, (d, b) in enumerate(zip(shape, block)))
+            idx = ", ".join("0" if a in waxes else n for a, n in enumerate(names))
+            in_specs.append(f"pl.BlockSpec({ext}, lambda {params}: ({idx}))")
         elif cluster.kind == "map":
             in_specs.append(spec)
         lines.append(f"        {slot} = {expr}")
@@ -664,4 +740,13 @@ def _emit_cluster_impl(cluster: Cluster) -> FusedKernel | DeclineReason:
         bytes_moved=bytes_moved,
         broadcast_in_kernel=n_small,
         broadcast_materialized=kinds.count("wide"),
+        window_in_kernel=len(windows),
+        window_materialized=sum(
+            cluster.kind == "map"
+            and window_axis(i) is not None
+            and i.fn.value.name == "slice_axis"
+            and i.abstract.shape == body_shape
+            for i in cluster.inputs
+        ),
+        cluster=cluster,
     )

@@ -236,12 +236,21 @@ def _fusion_section(g: Any, options: Any) -> dict:
             entry["bytes_moved"] = kernel.bytes_moved
             entry["broadcast_in_kernel"] = kernel.broadcast_in_kernel
             entry["broadcast_materialized"] = kernel.broadcast_materialized
+            entry["window_in_kernel"] = kernel.window_in_kernel
+            entry["window_materialized"] = kernel.window_materialized
         if reason is not None:
             entry["reason"] = reason.as_dict()
         clusters.append(entry)
+        kept = kernel.cluster.members if kernel is not None else c.members
         for m in c.members:
             member_of[m] = i
             cluster_reason[m] = reason.as_dict() if reason is not None else None
+            if m not in kept:  # a window the kernel could not hold
+                cluster_reason[m] = _reason(
+                    "exceeds-vmem-budget",
+                    "the window's whole axis does not fit the kernel's VMEM "
+                    "budget: the slice stays outside the kernel",
+                )
     nodes: list[dict] = []
     for n in toposort(g):
         if not isinstance(n, Apply):

@@ -11,6 +11,7 @@
 * ``lowering_blockers`` de-duplicates; ``try_lower`` caches per graph.
 """
 
+import math
 import os
 import re
 
@@ -145,6 +146,74 @@ SMALL_OPERANDS = [
      2 * _BODY5_BYTES + 16 * 1024 * 4, 2 * _BODY5_BYTES + 16 * 1024 * 4),
 ]
 
+# --- shifted windows of an operand, formed in the kernel
+
+
+def _causal_conv(x, w0, w1, w2, w3, b):
+    # the Mamba-2 mixer's width-4 causal convolution and SiLU
+    up = P.pad_zeros_axis(x, 1, 3, 0)
+    y = (
+        P.slice_axis(up, 1, 0, 16) * w0
+        + P.slice_axis(up, 1, 1, 17) * w1
+        + P.slice_axis(up, 1, 2, 18) * w2
+        + P.slice_axis(up, 1, 3, 19) * w3
+        + b
+    )
+    return y * P.sigmoid(y)
+
+
+def _slice_no_pad(x):
+    return P.tanh(P.slice_axis(x, 1, 0, 16)) * P.slice_axis(x, 1, 2, 18) + 1.0
+
+
+def _leading_axis(x):
+    # x[b] * x[b - 1]: the source is also a full operand of the body
+    up = P.pad_zeros_axis(x, 0, 1, 0)
+    return P.tanh(P.slice_axis(up, 0, 1, 5)) * P.slice_axis(up, 0, 0, 4) + x
+
+
+def _pad_used_outside(x):
+    up = P.pad_zeros_axis(x, 1, 1, 0)
+    total = P.reduce_sum(up, (0, 1, 2), False)
+    return P.tanh(P.slice_axis(up, 1, 0, 16)) * P.slice_axis(up, 1, 1, 17) + total
+
+
+def _lane_axis(x):
+    return P.tanh(P.slice_axis(x, 2, 0, 1024)) * P.slice_axis(x, 2, 1, 1025) + 1.0
+
+
+def _shared_slice(x):
+    # the recompute in a loop adjoint: a window also feeds a weight gradient
+    up = P.pad_zeros_axis(x, 1, 1, 0)
+    s0 = P.slice_axis(up, 1, 0, 16)
+    return P.tanh(s0) * P.slice_axis(up, 1, 1, 17) + 1.0, P.reduce_sum(s0, (0, 1, 2), False)
+
+
+def _x(*shape):
+    return jnp.linspace(-2, 2, math.prod(shape)).reshape(shape)
+
+
+_LANES = tuple(jnp.linspace(-1, 1, 1024) * (k + 1) for k in range(5))
+#: bytes of one (2, 16, 1024) f32 body
+_E = 2 * 16 * 1024 * 4
+
+#: (name, fn, example, (window_in_kernel, window_materialized), the
+#: window source's shape, or None when no window is read in the kernel,
+#: bytes_moved) — a source is read once, at its own size, whole along
+#: the window axis, at the default budget and at the tiled one alike; the
+#: lane-axis slices stay outside as two body-size operands
+WINDOWS = [
+    ("causal_conv", _causal_conv, (_x(2, 16, 1024), *_LANES), (4, 0), (2, 16, 1024),
+     2 * _E + 5 * 1024 * 4),
+    ("slice_no_pad", _slice_no_pad, (_x(2, 18, 1024),), (2, 0), (2, 18, 1024),
+     18 * _E // 16 + _E),
+    ("leading_axis", _leading_axis, (_x(4, 16, 1024),), (2, 0), (4, 16, 1024), 4 * _E),
+    ("pad_used_outside", _pad_used_outside, (_x(2, 16, 1024),), (2, 0), (2, 17, 1024),
+     17 * _E // 16 + _E + 4),
+    ("lane_axis", _lane_axis, (_x(2, 16, 1025),), (0, 0), None, 3 * _E),
+    ("shared_slice", _shared_slice, (_x(2, 16, 1024),), (2, 0), (2, 16, 1024), 2 * _E),
+]
+
 CORPUS = [
     ("grad_cube", build_grad_graph, _cube, 0, (_F32,)),
     (
@@ -203,6 +272,15 @@ def _optimized(build, fn, wrt, example):
 
 def _flat(r):
     return r if isinstance(r, tuple) else (r,)
+
+
+def _value_of(g, example, node):
+    """A node's value for the example, each primitive run on its own."""
+    if node in g.parameters:
+        return example[g.parameters.index(node)]
+    if not node.is_apply:
+        return node.value
+    return node.fn.value.impl(*(_value_of(g, example, a) for a in node.args))
 
 
 @pytest.mark.parametrize("name,build,fn,wrt,example", CORPUS, ids=[c[0] for c in CORPUS])
@@ -395,6 +473,113 @@ class TestCodegen:
         np.testing.assert_array_equal(got, np.asarray(jax.jit(k.oracle)(*args)))
         if name.startswith("row"):
             assert got.sum() == 64  # one max per row: the mask is not empty
+
+    @pytest.mark.parametrize("tiled", [False, True], ids=["whole", "tiled"])
+    @pytest.mark.parametrize(
+        "name,fn,example,counts,source,nbytes", WINDOWS, ids=[c[0] for c in WINDOWS]
+    )
+    def test_window_members(self, monkeypatch, name, fn, example, counts, source, nbytes,
+                            tiled):
+        """A body-shaped ``slice_axis`` along a non-lane axis, over a
+        cluster input or over a ``pad_zeros_axis`` of one, joins the map
+        cluster: the kernel reads the source once at its own shape, whole
+        along the window axis, and forms each window in VMEM.  A pad with
+        a user outside stays out (the windows read its output); a slice
+        with a user outside is copied into the kernel and stays in the
+        graph too; a lane-axis slice stays out.  Tiled (a 160 KiB budget)
+        blocks every other axis.  The kernel is bit-identical to the oracle
+        under jit, and so is the fused lowering to the unfused one."""
+        from repro.kernels import codegen
+
+        if tiled:
+            monkeypatch.setattr(codegen, "_VMEM_BUDGET", 160 * 1024)
+        g = _optimized(None, fn, 0, example)
+        (c,) = [c for c in partition_graph(g).clusters if c.kind == "map"]
+        k = emit_cluster(c)
+        assert k is not None and k.cluster is c
+        assert (k.window_in_kernel, k.window_materialized) == counts
+        assert len(c.windows) == counts[0]
+        assert k.bytes_moved == nbytes
+        inputs = [tuple(i.abstract.shape) for i in c.inputs]
+        if source is not None:
+            # one read of the source, and no shifted slice of it outside
+            assert source in inputs
+            assert not any(
+                i.is_apply and i.fn.value.name == "slice_axis" for i in c.inputs
+            )
+            kernel_src = k.source.split("def _make")[0]
+            assert "slice_axis" in kernel_src
+        else:
+            assert [i.fn.value.name for i in c.inputs] == ["slice_axis"] * 2
+        ops = [n.fn.value.name for n in c.order]
+        assert ("pad_zeros_axis" in ops) == (name in ("causal_conv", "leading_axis",
+                                                       "shared_slice"))
+        grid = [int(n) for n in re.search(r"grid=\(([\d, ]+)\)", k.source)[1].split(",")]
+        assert math.prod(grid) > 1 if tiled else math.prod(grid) == 1
+
+        tracer = Tracer()
+        with tracing(tracer):
+            fused = lower_graph(g, fuse=True)
+        (span,) = tracer.find("lower")
+        assert (span.attrs["window_in_kernel"], span.attrs["window_materialized"]) == counts
+        shared = {n.fn.value.name for n in c.order if n._id in c.shared}
+        assert shared == ({"pad_zeros_axis", "slice_axis"} if name == "shared_slice" else set())
+
+        args = [_value_of(g, example, i) for i in c.inputs]
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(k.pallas_interpret)(*args)), np.asarray(jax.jit(k.oracle)(*args))
+        )
+        set_kernel_mode("pallas_interpret")
+        for u, v in zip(_flat(jax.jit(lower_graph(g))(*example)), _flat(jax.jit(fused)(*example))):
+            np.testing.assert_array_equal(np.asarray(u), np.asarray(v))
+
+    def test_window_over_budget_falls_back(self, monkeypatch):
+        """When a window's whole axis cannot fit the VMEM budget, the
+        kernel is the one for the cluster without its windows: the slices
+        and the pad run outside, as four body-size operands, and are
+        counted as materialized."""
+        from repro.kernels import codegen
+
+        # the windows' (1, 64, 128) block needs 256 KiB; the cluster
+        # without them blocks rows, (8, 256), in 200 KiB
+        monkeypatch.setattr(codegen, "_VMEM_BUDGET", 220 * 1024)
+        lanes = tuple(jnp.linspace(-1, 1, 256) * (k + 1) for k in range(5))
+        example = (_x(2, 64, 256), *lanes)
+
+        def conv64(x, w0, w1, w2, w3, b):
+            up = P.pad_zeros_axis(x, 1, 3, 0)
+            y = (
+                P.slice_axis(up, 1, 0, 64) * w0
+                + P.slice_axis(up, 1, 1, 65) * w1
+                + P.slice_axis(up, 1, 2, 66) * w2
+                + P.slice_axis(up, 1, 3, 67) * w3
+                + b
+            )
+            return y * P.sigmoid(y)
+
+        g = _optimized(None, conv64, 0, example)
+        (c,) = partition_graph(g).clusters
+        assert len(c.windows) == 4
+        k = emit_cluster(c)
+        assert k is not None and k.cluster is not c
+        parent = c.without_windows()
+        assert k.cluster.order == parent.order and k.cluster.inputs == parent.inputs
+        assert [i.fn.value.name for i in k.cluster.inputs if i.is_apply] == ["slice_axis"] * 4
+        assert (k.window_in_kernel, k.window_materialized) == (0, 4)
+        assert "slice_axis" not in k.source.split("def _make")[0]
+        # four shifted copies and the output at the body's size, the lanes once
+        assert k.bytes_moved == 5 * 2 * 64 * 256 * 4 + 5 * 256 * 4
+
+        tracer = Tracer()
+        with tracing(tracer):
+            fused = lower_graph(g, fuse=True)
+        (span,) = tracer.find("lower")
+        assert (span.attrs["window_in_kernel"], span.attrs["window_materialized"]) == (0, 4)
+        assert fused.__lowered_source__.count("# slice_axis") == 4
+        set_kernel_mode("pallas_interpret")
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(lower_graph(g))(*example)), np.asarray(jax.jit(fused)(*example))
+        )
 
     @pytest.mark.parametrize("batch", [16, 32])
     def test_tanh_lm_kernels_keep_their_source(self, batch):

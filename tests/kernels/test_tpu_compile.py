@@ -171,12 +171,10 @@ def _clusters_with_loop_bodies(g):
 
 
 @pytest.fixture(scope="module")
-def hybrid_ssd_kernels():
-    """The distinct generated kernels at the SSD's (B, H, NC, Q, Q) body
-    in the Granite 4.0-H Micro loss+gradient at published widths, B 2 x S
-    2048 (the benchmark cell's): the forward's masked decays times C B^T,
-    the recompute's decays and the adjoint's product, by kernel name with
-    its number dropped."""
+def hybrid_clusters():
+    """The Granite 4.0-H Micro configuration and the fusion clusters of
+    its loss+gradient at published widths, B 2 x S 2048 (the benchmark
+    cell's), loop bodies included."""
     with open(os.path.join(_ROOT, "bench", "configs", "myia-granite4h-micro.json")) as f:
         dims = HybridDims(json.load(f))
     B, S = 2, 2048
@@ -184,13 +182,28 @@ def hybrid_ssd_kernels():
     params = jax.eval_shape(lambda: init_hybrid_params(dims, jax.random.PRNGKey(0)))
     tokens = jax.ShapeDtypeStruct((B, S), jnp.int32)
     g = vag.optimized_graph(*params, tokens, tokens)
-    body = (B, dims.heads, S // dims.chunk, dims.chunk, dims.chunk)
+    return dims, B, S, _clusters_with_loop_bodies(g)
+
+
+def _distinct_kernels(clusters, body):
+    """The distinct generated kernels at ``body``, by kernel name with its
+    number dropped."""
     found = {}
-    for c in _clusters_with_loop_bodies(g):
+    for c in clusters:
         if tuple(c.body_shape) == body:
             k = emit_cluster(c)
             found.setdefault(re.sub(r"^fused_map\d+_", "", k.name), (c, k))
-    return body, found
+    return found
+
+
+@pytest.fixture(scope="module")
+def hybrid_ssd_kernels(hybrid_clusters):
+    """The distinct generated kernels at the SSD's (B, H, NC, Q, Q) body:
+    the forward's masked decays times C B^T, the recompute's decays and
+    the adjoint's product."""
+    dims, B, S, clusters = hybrid_clusters
+    body = (B, dims.heads, S // dims.chunk, dims.chunk, dims.chunk)
+    return body, _distinct_kernels(clusters, body)
 
 
 @pytest.mark.parametrize("kernel", ["sub_where_exp_mul", "sub_where_exp", "mul"])
@@ -210,6 +223,34 @@ def test_hybrid_ssd_kernels_broadcast_in_vmem(sds, hybrid_ssd_kernels, kernel):
         n for n, dims, op, _ in _HLO_INSTR.findall(text)
         if op == "broadcast" and math.prod(int(d) for d in dims.split(",") if d) == size
     ]
+
+
+@pytest.mark.parametrize(
+    "kernel", ["pad_zeros_axis_slice_axis_mul_add_sigmoid", "pad_zeros_axis_slice_axis_mul_add"]
+)
+def test_hybrid_conv_kernel_reads_its_input_once(sds, hybrid_clusters, kernel):
+    """The Mamba-2 mixer's width-4 causal conv over ``xBC`` (B, S, conv
+    width), in the forward and in the backward's recompute: the kernel
+    takes ``xBC`` once at its own size, whole along S, and forms the four
+    shifted windows in VMEM; it compiles through Mosaic, and no shifted
+    slice or pad of ``xBC`` is left in the HLO around it."""
+    dims, B, S, clusters = hybrid_clusters
+    body = (B, S, dims.conv_dim)
+    cluster, k = _distinct_kernels(clusters, body)[kernel]
+    assert (k.window_in_kernel, k.window_materialized) == (4, 0) and k.cluster is cluster
+    size = math.prod(body)
+    full = [n for n in cluster.inputs if math.prod(n.abstract.shape) == size]
+    assert [tuple(n.abstract.shape) for n in full] == [body]
+    # one read of xBC and one write, and the conv's (C,) weights and bias
+    assert k.bytes_moved == 2 * size * 4 + 5 * dims.conv_dim * 4
+    avals = [sds(n.abstract.shape, n.abstract.dtype) for n in cluster.inputs]
+    text = _compiled_text(k.pallas_compiled, *avals)
+    assert _KERNEL in text
+    instrs = _HLO_INSTR.findall(text)
+    assert not [n for n, _, op, _ in instrs if op in ("slice", "pad", "concatenate")]
+    (call_args,) = [args for _, _, op, args in instrs if op == "custom-call"]
+    elems = {n: math.prod(int(d) for d in dims_.split(",") if d) for n, dims_, _, _ in instrs}
+    assert [elems.get(o) == size for o in re.findall(r"%([\w.-]+)", call_args)].count(True) == 1
 
 
 def test_lm_reduce_cluster_over_vmem_declines(lm_clusters):

@@ -17,7 +17,9 @@ import pytest
 from benchmarks.bench_compile_time import _fallback_corpus
 from repro.core import parse_function
 from repro.core.api import CompileOptions, grad
+from repro.core.primitives import pad_zeros_axis as _pad
 from repro.core.primitives import reduce_sum as _rsum
+from repro.core.primitives import slice_axis as _slice
 from repro.core.primitives import tanh as _tanh
 from repro.obs.explain import ExplainReport, explain_graph, format_graph
 
@@ -142,6 +144,37 @@ def test_myia_function_explain_resolves_transforms():
     ]
     fused = [n for n in fus["nodes"] if n["decision"] == "fused"]
     assert fused, "no node actually joined a cluster"
+
+
+def _shifted(x):
+    up = _pad(x, 1, 1, 0)
+    return _tanh(_slice(up, 1, 0, 16)) * _slice(up, 1, 1, 17) + 1.0
+
+
+@pytest.mark.parametrize("budget", [None, 40 * 1024], ids=["fits", "over_budget"])
+def test_window_members_reported(monkeypatch, budget):
+    """The explain report counts a kernel's windows per cluster; the pad
+    and slices it reads in VMEM are fused, and when their whole axis does
+    not fit the budget they are unfused with that reason."""
+    from repro.kernels import codegen
+
+    if budget is not None:
+        monkeypatch.setattr(codegen, "_VMEM_BUDGET", budget)
+    x = jnp.ones((2, 16, 1024), jnp.float32)
+    rep = explain_graph(parse_function(_shifted), (x,), CompileOptions(fuse=True))
+    _assert_structured(rep, "_shifted")
+    (c,) = rep["fusion"]["clusters"]
+    windows = [n for n in rep["fusion"]["nodes"] if n["op"] in ("pad_zeros_axis", "slice_axis")]
+    assert sorted(n["op"] for n in windows) == ["pad_zeros_axis", "slice_axis", "slice_axis"]
+    assert c["verdict"] == "emitted"
+    if budget is None:
+        assert (c["window_in_kernel"], c["window_materialized"]) == (2, 0)
+        assert all(n["decision"] == "fused" for n in windows)
+    else:
+        # the (1, 16, 128) windowed block needs 48 KiB, the cluster
+        # without its windows (8, 128) rows in 36 KiB
+        assert (c["window_in_kernel"], c["window_materialized"]) == (0, 2)
+        assert all(n["reason"]["kind"] == "exceeds-vmem-budget" for n in windows)
 
 
 def test_signature_and_phases_recorded():
